@@ -13,7 +13,7 @@ Two measurements:
   to 256 MB. Per budget: the planned stripe geometry
   (``superchunk_words_for`` / ``plan_stream``), the modeled peak device
   bytes (``estimate_stripe_bytes``, the number the acceptance tests bound
-  with ``compat.memory_analysis``), the footprint reduction vs the
+  with ``streaming.measure_footprint``), the footprint reduction vs the
   monolithic encode, and the cross-stripe overlap speedup: S double-
   buffered stripes cost ``S*C + n - 1`` chain ticks where sequential
   stripe launches cost ``S*(C + n - 1)`` (Repair Pipelining's cross-

@@ -7,11 +7,12 @@ tick_overhead), and the pipeline plan parameters (num_chunks, stagger) —
 and persists everything to the JSON tuning cache, so production runs under
 ``RAPIDRAID_TUNE=cached`` (the default) start warm and never probe.
 
-The chain probes need ``n`` local jax devices. When fewer are available
-(the usual CPU case) the CLI re-executes itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=n`` — forced host
-devices share one CPU, which is exactly the geometry the cached plans will
-serve under test/CI runs on this machine.
+The chain probes need ``n`` local jax devices. On the CPU backend the CLI
+asks for ``n`` forced host devices before JAX starts (forced host devices
+share one CPU, which is exactly the geometry the cached plans will serve
+under test/CI runs), so everything runs in this one process. On an
+accelerator the probes run on the chips the host has, and the CLI stops
+with an error when that is fewer than ``n``.
 
 Examples::
 
@@ -24,10 +25,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
-
-_REEXEC_ENV = "_RAPIDRAID_AUTOTUNE_REEXEC"
 
 
 def _build_parser() -> argparse.ArgumentParser:
@@ -67,18 +65,17 @@ def main(argv=None) -> int:
 
     import jax
 
-    if len(jax.devices()) < args.n and _REEXEC_ENV not in os.environ:
-        # not enough devices for the chain probes: re-exec with forced XLA
-        # host devices (guarded against a re-exec loop)
-        env = dict(os.environ)
-        env[_REEXEC_ENV] = "1"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            f" --xla_force_host_platform_device_count="
-                            f"{args.n}").strip()
-        env[autotune.TUNE_ENV] = "search"
-        return subprocess.call([sys.executable, "-m", "repro.autotune",
-                                *(argv if argv is not None
-                                  else sys.argv[1:])], env=env)
+    from repro.core import jitcache
+
+    # must precede the backend's start-up; it changes only the CPU backend
+    jax.config.update("jax_num_cpu_devices", args.n)
+    jitcache.enable_persistent_cache()
+    devices = jax.devices()
+    if len(devices) < args.n:
+        print(f"repro.autotune: the chain probes of an n={args.n} code need "
+              f"{args.n} devices; this host has {len(devices)} "
+              f"{devices[0].platform} device(s)", file=sys.stderr)
+        return 2
 
     from repro.core.codes import registry
 

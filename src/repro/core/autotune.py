@@ -485,6 +485,8 @@ def calibrate_chain(code, nwords: int = 1 << 15,
     (backend, l). Needs ``code.n`` local devices; raises otherwise (the
     CLI forces host devices).
     """
+    import jax
+
     from repro.storage import chain as chain_lib
 
     lanes = gf.LANES[code.l]
@@ -499,14 +501,24 @@ def calibrate_chain(code, nwords: int = 1 << 15,
                         size=(code.k, nwords)).astype(gf.WORD_DTYPE[code.l])
     block_bytes = data[0].nbytes
     _stats["probes"] += 1
-    samples, hlo = [], {}
+    def run(c: int) -> None:
+        jax.block_until_ready(
+            chain_lib.pipelined_encode(code, data, num_chunks=c))
+
+    # compile (and tick-probe) every chunk count first, then time them in
+    # interleaved rounds: a slow stretch of the process (start-up, a busy
+    # neighbour) then costs each count one round, which the median drops
     for c in chunk_counts:
-        t = _median_time(
-            lambda: chain_lib.pipelined_encode(code, data, num_chunks=c),
-            iters)
-        samples.append((c, t))
-        cost = program_cost(chain_lib.encode_program(code, nwords, c), data)
-        hlo[str(c)] = cost
+        run(c)
+    times: dict[int, list[float]] = {c: [] for c in chunk_counts}
+    for _ in range(iters):
+        for c in chunk_counts:
+            t0 = time.perf_counter()
+            run(c)
+            times[c].append(time.perf_counter() - t0)
+    samples = [(c, sorted(ts)[len(ts) // 2]) for c, ts in times.items()]
+    hlo = {str(c): program_cost(chain_lib.encode_program(code, nwords, c),
+                                data) for c in chunk_counts}
     topo, pred = topo_lib.fit_chain_constants(samples, code.n, code.k,
                                               block_bytes)
     rel_err = [abs(p - t) / t for (_, t), p in zip(samples, pred)]

@@ -1,70 +1,18 @@
-"""JAX version compatibility shims.
+"""The one ``shard_map`` every chain program in ``repro`` is built with.
 
-The distributed runtime targets the modern API (``jax.shard_map``,
-``lax.pcast``); older jaxlibs (< 0.5) ship the same functionality as
-``jax.experimental.shard_map`` without varying-axes tracking. Everything in
-``repro`` goes through these wrappers so one import site owns the skew.
+The chain, multi-object, repair and checkpoint programs run Pallas kernels
+inside ``shard_map``. With varying-manual-axes checking on, a
+``pallas_call`` needs a ``vma`` on every output shape, and the Pallas
+interpreter (the CPU path the tests take) then rejects the mixed-vma
+operands of its own block slicing. The check is therefore off for these
+programs; each one states its sharding through ``in_specs``/``out_specs``.
 """
 from __future__ import annotations
 
 import jax
-from jax import lax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` when present, else the experimental fallback.
-
-    The fallback disables replication checking: the chain pipelines carry
-    per-device state through ``lax.scan``, which the old checker cannot
-    prove replicated (the modern API expresses this via ``lax.pcast``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a mesh axis inside shard_map, across jax versions."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax.core as jc
-    frame = jc.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-def pcast_varying(x, axis_name: str):
-    """Mark ``x`` device-varying along ``axis_name`` under manual sharding.
-
-    No-op on jaxlibs without ``lax.pcast`` (their shard_map does not track
-    varying manual axes, so the cast is unnecessary).
-    """
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    return x
-
-
-def memory_analysis(compiled) -> int | None:
-    """Peak live device bytes of a compiled program, across jax versions.
-
-    Newer jaxlibs expose ``peak_memory_in_bytes``; older ones only the
-    argument/output/temp breakdown, whose sum bounds the peak (the number
-    the streaming footprint tests budget against). Returns None when the
-    backend provides no memory analysis at all (some CPU plugins).
-    """
-    try:
-        mem = compiled.memory_analysis()
-    except (AttributeError, NotImplementedError):
-        return None
-    if mem is None:
-        return None
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    try:
-        return int(mem.argument_size_in_bytes + mem.output_size_in_bytes
-                   + mem.temp_size_in_bytes)
-    except AttributeError:
-        return None
+    """``jax.shard_map`` with varying-manual-axes checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -9,7 +9,7 @@ Three execution styles, all bit-exact against each other:
    (data-dependent gathers; fine on CPU, slow on TPU VPU).
 3. Packed **bit-plane** arithmetic — the TPU-native path: a multiply by a
    *static* coefficient ``c`` is ``xor_j bit_j(x) * (c * alpha^j)``, with 4
-   bytes (or 2 halfwords) packed per 32-bit lane. No gathers; pure
+   bytes (or 2 halfwords) packed per 32-bit lane (``pack_u32``). No gathers; pure
    shift/mask/mul/xor, which vectorizes on the TPU VPU. The Pallas kernels in
    ``repro.kernels.gf_encode`` are built on this formulation.
 """
@@ -184,25 +184,34 @@ def gf_matmul(A, B: jax.Array, l: int) -> jax.Array:
 # Packed bit-plane arithmetic (TPU-native formulation)
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames="l")
 def pack_u32(x: jax.Array, l: int) -> jax.Array:
     """Pack words of GF(2^l) (uint8/uint16) along the last dim into uint32 lanes.
 
-    Last dim must be a multiple of LANES[l]. Little-endian within the lane.
+    Last dim must be a multiple of LANES[l]. The layout is planar: the last
+    axis splits into LANES[l] contiguous parts of Q words, and lane j holds
+    word i*Q + j in bits [i*l, (i+1)*l). Every packed GF operation is
+    positionwise, so any bijective layout codes identically; this one needs
+    only contiguous slices, where packing adjacent words into a lane makes
+    XLA materialize a (..., Q, LANES) array whose minor axis the TPU pads to
+    128 lanes (64x the bytes at GF(2^16)).
     """
     lanes = LANES[l]
     assert x.shape[-1] % lanes == 0, (x.shape, lanes)
-    xs = x.reshape(x.shape[:-1] + (x.shape[-1] // lanes, lanes)).astype(jnp.uint32)
-    out = xs[..., 0]
+    q = x.shape[-1] // lanes
+    out = x[..., :q].astype(jnp.uint32)
     for i in range(1, lanes):
-        out = out | (xs[..., i] << (i * l))
+        out = out | (x[..., i * q:(i + 1) * q].astype(jnp.uint32) << (i * l))
     return out
 
 
+@functools.partial(jax.jit, static_argnames="l")
 def unpack_u32(xp: jax.Array, l: int) -> jax.Array:
-    lanes = LANES[l]
+    """Inverse of ``pack_u32``: (..., Q) uint32 lanes -> (..., LANES[l]*Q)."""
     mask = jnp.uint32((1 << l) - 1)
-    parts = [((xp >> (i * l)) & mask).astype(WORD_DTYPE[l]) for i in range(lanes)]
-    return jnp.stack(parts, axis=-1).reshape(xp.shape[:-1] + (xp.shape[-1] * lanes,))
+    return jnp.concatenate(
+        [((xp >> (i * l)) & mask).astype(WORD_DTYPE[l])
+         for i in range(LANES[l])], axis=-1)
 
 
 def bitplane_consts(c: int, l: int) -> list[int]:

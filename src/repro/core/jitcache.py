@@ -35,10 +35,37 @@ call ``clear()`` at their own epoch boundaries.
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Callable
+
+#: JAX reads this variable itself; where it is set the cache stays there
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed fallback inside the checkout: the cache directory is part of
+#: each entry's key, so it must not move from run to run
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 _programs: dict[Any, Callable] = {}
 _stats = {"hits": 0, "misses": 0}
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; -> its directory.
+
+    Compiled programs then survive the process, so a second run of an
+    entry point skips the compiles the first one paid for. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and nothing
+    is set here; otherwise the cache goes to ``CHECKOUT_CACHE_DIR``.
+    Called by the entry points (``chip_smoke.py``, ``python -m repro.*``),
+    never on import.
+    """
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def get(key: Any, builder: Callable[[], Callable]) -> Callable:
@@ -75,6 +102,13 @@ def compile_counts() -> dict[str, int]:
         size = getattr(fn, "_cache_size", None)
         out[repr(key)] = int(size()) if callable(size) else -1
     return out
+
+
+def programs(entry: str) -> list[Callable]:
+    """The cached programs of one entry point (``key[0] == entry``), in
+    build order — for inspecting what a call compiled (``.lower(...)``)."""
+    return [fn for key, fn in _programs.items()
+            if isinstance(key, tuple) and key and key[0] == entry]
 
 
 def entry_counts(entry: str) -> dict[str, int]:

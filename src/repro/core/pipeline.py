@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import compat
-
 
 def num_ticks(num_chunks: int, n_stages: int) -> int:
     return num_chunks + n_stages - 1
@@ -72,7 +70,7 @@ def software_pipeline(
 
     Returns the final ``out``.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     pos = chain_pos(lax.axis_index(axis_name), n, reverse)
     perm = chain_perm(n, reverse)
 
@@ -86,10 +84,8 @@ def software_pipeline(
         wire_next = lax.ppermute(wire_out, axis_name, perm)
         return (wire_next, out), None
 
-    # carries are device-varying under shard_map's manual-axes tracking
-    carry0 = jax.tree.map(lambda x: compat.pcast_varying(x, axis_name),
-                          (wire_init, out_init))
-    (_, out), _ = lax.scan(tick, carry0, jnp.arange(num_ticks(num_chunks, n)))
+    (_, out), _ = lax.scan(tick, (wire_init, out_init),
+                           jnp.arange(num_ticks(num_chunks, n)))
     return out
 
 
@@ -156,7 +152,7 @@ def staggered_pipeline(
     algebra is position-based, so it is untouched by the direction.
     """
     assert stagger >= 1 and num_objects >= 1
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     pos = chain_pos(lax.axis_index(axis_name), n, reverse)
     perm = chain_perm(n, reverse)
     W = window_size(num_chunks, num_objects, stagger)
@@ -180,7 +176,5 @@ def staggered_pipeline(
         return (wire_next, out), None
 
     wire0 = jnp.broadcast_to(wire_init[None], (W,) + wire_init.shape)
-    carry0 = jax.tree.map(lambda x: compat.pcast_varying(x, axis_name),
-                          (wire0, out_init))
-    (_, out), _ = lax.scan(tick, carry0, jnp.arange(total))
+    (_, out), _ = lax.scan(tick, (wire0, out_init), jnp.arange(total))
     return out

@@ -116,8 +116,8 @@ def estimate_stripe_bytes(code, sc_words: int, *, rows_in: int | None = None,
     (n, max_blocks, W) uint32 local view, the packed wire/output, and the
     unpacked (rows_out, W) result — times 2 for the double buffer (two
     stripes in flight). A deliberate over-count: the streaming budget is a
-    guarantee, so the model errs high and ``compat.memory_analysis``
-    verifies the real number in tests/benchmarks.
+    guarantee, so the model errs high and ``measure_footprint`` verifies
+    the real number in tests/benchmarks.
     """
     wb = code.l // 8
     rows_in = code.k if rows_in is None else rows_in
@@ -136,7 +136,7 @@ def superchunk_words_for(footprint_bytes: int, code, num_chunks: int) -> int:
 
     Inverts ``estimate_stripe_bytes`` and floors to one pipeline granule —
     callers that need a hard guarantee assert the compiled program's
-    ``compat.memory_analysis`` against the budget (the streaming tests do).
+    ``measure_footprint`` against the budget (the streaming tests do).
     """
     granule = gf.LANES[code.l] * num_chunks
     lo, hi = granule, granule
@@ -247,13 +247,12 @@ def run_words(program: Callable, data: np.ndarray, plan: StreamPlan, *,
     return out_full
 
 
-def measure_footprint(fn: Callable, *sample_args) -> int | None:
+def measure_footprint(fn: Callable, *sample_args) -> int:
     """Peak live device bytes of ``fn`` compiled for ``sample_args``.
 
-    AOT-lowers the jitted callable and reads ``compat.memory_analysis`` —
-    the number the streaming acceptance tests bound against the footprint
-    budget. Returns None when the backend exposes no memory analysis.
+    AOT-compiles the jitted callable and reads its memory analysis — the
+    number the streaming acceptance tests bound against the footprint
+    budget.
     """
-    from repro.core import compat
-    lowered = fn.lower(*sample_args)
-    return compat.memory_analysis(lowered.compile())
+    compiled = fn.lower(*sample_args).compile()
+    return int(compiled.memory_analysis().peak_memory_in_bytes)

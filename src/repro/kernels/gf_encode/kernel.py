@@ -27,9 +27,11 @@ object index the leading pallas grid dimension, so ONE fused launch encodes
 O objects and the launch + coefficient-plane overhead is amortized across
 the batch.
 
-On CPU (this container) the kernels run under ``interpret=True``; the
-BlockSpecs below are the real TPU tiling (last dim a multiple of 128 lanes,
-working set sized for ~16 MB VMEM).
+Every kernel takes ``interpret`` as a required keyword: the one place that
+decides it is ``ops._interpret_default`` (the Pallas interpreter off-TPU,
+the compiled Mosaic kernel on a TPU). The BlockSpecs below are the
+real TPU tiling (last dim a multiple of 128 lanes, working set sized for
+~16 MB VMEM).
 """
 from __future__ import annotations
 
@@ -66,7 +68,7 @@ def _encode_body(x_ref, o_ref, *, M: np.ndarray, l: int):
 
 
 def gf_encode_kernel(M: np.ndarray, data_packed: jax.Array, l: int,
-                     block: int = DEFAULT_BLOCK, interpret: bool = True):
+                     block: int = DEFAULT_BLOCK, *, interpret: bool):
     """Static-coeff encode, single object or a batch of objects in ONE launch.
 
     (k, Bp) packed -> (rows, Bp), or (O, k, Bp) -> (O, rows, Bp) with the
@@ -114,7 +116,7 @@ def _chain_step_body(x_ref, local_ref, bpsi_ref, bxi_ref, c_ref, xout_ref,
 
 def chain_step_kernel(x_in: jax.Array, local: jax.Array, bp_psi: jax.Array,
                       bp_xi: jax.Array, l: int, block: int = DEFAULT_BLOCK,
-                      interpret: bool = True):
+                      *, interpret: bool):
     """Fused RapidRAID node step on one chunk, for 1 object or a batch.
 
     Single object: x_in (1, C) uint32 wire, local (max_b, C) packed replica
@@ -163,7 +165,7 @@ def _repair_step_body(x_ref, local_ref, bp_ref, o_ref, *, l: int):
 
 def repair_step_kernel(x_in: jax.Array, local: jax.Array, bp: jax.Array,
                        l: int, block: int = DEFAULT_BLOCK,
-                       interpret: bool = True):
+                       *, interpret: bool):
     """Fused GF inner-product repair step (repair pipelining, one helper).
 
     The helper adds its term of ``c_lost = xor_h R[:, h] * c_h`` to the
@@ -240,7 +242,7 @@ def _mxu_body(x_ref, mb_ref, o_ref, *, l: int, rows: int, k: int):
 
 def gf_encode_mxu_kernel(M: np.ndarray, data_words: jax.Array, l: int,
                          block: int = DEFAULT_MXU_BLOCK,
-                         interpret: bool = True):
+                         *, interpret: bool):
     """Bit-lifted MXU encode: (k, B) words (int32) -> (rows, B) words (int32)."""
     M = np.asarray(M)
     rows, k = M.shape

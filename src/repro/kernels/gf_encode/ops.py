@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the GF encode kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies then execute exactly as written, validating logic + tiling),
-and to False on a real TPU backend.
+``interpret=None`` resolves through ``_interpret_default``, the one place
+that decides it: the Pallas interpreter off-TPU (the kernel bodies then
+execute exactly as written, validating logic + tiling) and the compiled
+kernel on a TPU, never the interpreter there.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from repro.kernels.gf_encode import kernel
 
 
 def _interpret_default() -> bool:
+    """The Pallas interpreter off-TPU; the compiled kernel on a TPU."""
     return jax.default_backend() != "tpu"
 
 

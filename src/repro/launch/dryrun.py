@@ -20,6 +20,7 @@ import jax                                # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ARCHS, get_config, shapes as shapes_lib  # noqa: E402
+from repro.core import jitcache           # noqa: E402
 from repro.launch import cost_model       # noqa: E402
 from repro.launch import hlo as hlo_lib   # noqa: E402
 from repro.launch import roofline as rl   # noqa: E402
@@ -201,6 +202,7 @@ def main() -> int:
     ap.add_argument("--moe-chunk", type=int, default=0,
                     help="MoE dispatch window (0 = whole sequence)")
     args = ap.parse_args()
+    jitcache.enable_persistent_cache()
 
     os.makedirs(args.out, exist_ok=True)
     meshes = {"pod1": [False], "pod2": [True], "both": [False, True]}[args.mesh]

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.core import jitcache
 from repro.models import model as model_lib
 from repro.train import steps
 
@@ -60,6 +61,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=32)
     args = ap.parse_args()
+    jitcache.enable_persistent_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = model_lib.init(jax.random.PRNGKey(0), cfg)

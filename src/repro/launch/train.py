@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointConfig, CheckpointManager, place
 from repro.configs import get_config
+from repro.core import jitcache
 from repro.data import pipeline as data_lib
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as model_lib
@@ -114,6 +115,7 @@ def main() -> None:
     ap.add_argument("--data", default="", help="binary token corpus path")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    jitcache.enable_persistent_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     ocfg = adamw.OptConfig(peak_lr=args.lr, warmup_steps=max(args.steps // 20, 5),

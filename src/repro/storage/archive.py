@@ -9,9 +9,10 @@ This is the paper's lifecycle applied to training checkpoints:
    encoding (paper §V).
 2. **archive_step** — the migration: the n nodes run the pipelined encode
    (each node combines what it stores with the running combination from its
-   predecessor — ``repro.storage.chain`` over a device chain, or the host
-   oracle off-device), each node keeps its coded block c_i, replicas are
-   dropped. Storage falls from 2x to n/k (1.45x for (16,11)).
+   predecessor — ``repro.storage.chain`` over a device chain, or one fused
+   kernel launch on the default device when there are fewer devices than
+   nodes), each node keeps its coded block c_i, replicas are dropped.
+   Storage falls from 2x to n/k (1.45x for (16,11)).
    **archive_many** batches the migration: B pending steps are encoded
    concurrently through the staggered multi-chain (``repro.storage.multi``)
    or, off-device, one fused batched pallas launch — the paper's
@@ -295,9 +296,7 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
             code, data_w, num_chunks=nc,
             order=_device_order(perm, sched is not None)))
     else:
-        # matrix-form host encode (bit-identical to the chain for
-        # RapidRAID; the only encode for non-chain families)
-        coded_w = code.encode_np(np.asarray(data_w))
+        coded_w = _fused_encode(code, data_w[None], acfg.l)[0]
     coded = _u8(coded_w)
     coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
 
@@ -434,6 +433,20 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     return manifest
 
 
+def _fused_encode(code, objs_w: np.ndarray, l: int) -> np.ndarray:
+    """(O, k, B) object words -> (O, n, B) codeword words in ONE fused
+    kernel launch on the default device, the object axis on the kernel grid.
+
+    The message view is the identity for positionwise codes and the
+    sub-packetized (M_sub, W) layout for regenerating codes, so EVERY
+    family encodes through the same fused GF kernel.
+    """
+    from repro.kernels.gf_encode import ops as kernel_ops
+    msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
+    rows = np.asarray(kernel_ops.encode_auto(code.G, jnp.asarray(msgs), l))
+    return rows.reshape(len(objs_w), code.n, -1)
+
+
 def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
                    code, perm: np.ndarray, num_chunks: int, stagger: int,
                    use_devices: bool, manifests: dict[int, dict],
@@ -441,7 +454,6 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
                    ) -> dict[int, dict]:
     """Encode one rectangular (same block length, same placement) batch of
     hot steps and place/manifest the coded blocks."""
-    from repro.kernels.gf_encode import ops as kernel_ops
     # blocks are loaded one group at a time (and released after the
     # group's encode) so peak host memory is one group, not the batch
     objs_w = np.stack([_words(hot_load(store, s, manifests[s]), acfg.l)
@@ -457,14 +469,7 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
             code, objs_w, num_chunks=nc, stagger=stagger,
             order=_device_order(perm, sched is not None)))
     else:
-        # one fused batched kernel launch over the whole group; the
-        # message view is the identity for positionwise codes and the
-        # sub-packetized (M_sub, W) layout for regenerating codes, so
-        # EVERY family encodes through the same fused GF kernel
-        msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
-        rows = np.asarray(kernel_ops.encode_auto(
-            code.G, jnp.asarray(msgs), acfg.l))
-        coded_w = rows.reshape(len(grp), code.n, -1)
+        coded_w = _fused_encode(code, objs_w, acfg.l)
     out: dict[int, dict] = {}
     for b, step in enumerate(grp):
         coded = _u8(coded_w[b])

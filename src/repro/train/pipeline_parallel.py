@@ -42,7 +42,7 @@ def _stage_body(stage_fn: Callable, n_micro: int):
 
     def body(params, xs):
         params = jax.tree.map(lambda a: a[0], params)
-        n = compat.axis_size(AXIS)
+        n = lax.axis_size(AXIS)
         idx = lax.axis_index(AXIS)
 
         def step_fn(wire_in, out, ch, active):

@@ -103,6 +103,28 @@ def test_archive_roundtrip_and_heal(code, tmp_path):
     assert arc.read_range(store, 2, acfg, 100, 500) == want[100:600]
 
 
+def test_archive_step_fused_encode_matches_oracle(code, tmp_path,
+                                                  monkeypatch):
+    """``archive_step`` with fewer devices than nodes codes in the fused
+    kernel, not host numpy, and stores exactly ``encode_np``'s shards."""
+    store = obj.NodeStore(str(tmp_path), N)
+    acfg = arc.ArchiveConfig(n=N, k=K, l=L, family=code.family,
+                             num_chunks=4)
+    data = _payload(code, B=512, seed=3)
+    arc.hot_save(store, 1, data.view(np.uint8), acfg)
+
+    def no_host_coding(*args):
+        raise AssertionError("archive_step coded on the host")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(gf, "gf_matmul_np", no_host_coding)
+        manifest = arc.archive_step(store, 1, acfg)
+    want = code.encode_np(data)
+    for pos in range(N):
+        got = store.get(manifest["perm"][pos], arc.ARC.format(step=1, i=pos))
+        assert got == want[pos].tobytes(), pos
+
+
 def test_repair_transfer_model_is_honest(code):
     """``repair_transfer_words`` equals what a single-shard repair reads."""
     B = 256

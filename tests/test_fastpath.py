@@ -15,6 +15,8 @@ Two families of regression tests:
 Multi-device paths run in subprocesses (``tests/subproc.py``); the
 host-side cache plumbing tests run inline.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -141,8 +143,48 @@ def test_jitcache_get_and_stats():
     assert st["hits"] == 1 and st["misses"] == 1 and st["size"] == 1
     # non-jit programs report -1 in compile_counts (no introspection)
     assert jitcache.compile_counts() == {repr(key): -1}
+    assert jitcache.programs("unit") == [fn1]
+    assert jitcache.programs("other") == []
     jitcache.clear()
     assert jitcache.stats() == {"hits": 0, "misses": 0, "size": 0}
+
+
+@pytest.fixture
+def compile_cache_config():
+    """Restore JAX's compile-cache directory after a test changes it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_persistent_cache_honours_env(monkeypatch, tmp_path,
+                                      compile_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX keeps the cache there and
+    nothing is set in code."""
+    import jax
+    from repro.core import jitcache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert jitcache.enable_persistent_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_persistent_cache_fixed_path_in_checkout(monkeypatch,
+                                                 compile_cache_config):
+    """Without the variable the cache goes to one fixed directory inside
+    the checkout, the same on every call (it is part of the cache key)."""
+    import jax
+    from repro.core import jitcache
+    from tests.subproc import REPO
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = jitcache.enable_persistent_cache()
+    assert first == jitcache.enable_persistent_cache()
+    assert first == jitcache.CHECKOUT_CACHE_DIR
+    assert first == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
 
 
 def test_bitplane_table_matches_scalar_consts():

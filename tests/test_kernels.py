@@ -129,10 +129,10 @@ def test_kernel_raises_not_asserts_on_bad_shapes():
     M = np.ones((2, 2), dtype=np.uint8)
     with pytest.raises(ValueError):
         kernel.gf_encode_kernel(M, jnp.zeros((2, 3), jnp.uint32), 8,
-                                block=2)
+                                block=2, interpret=True)
     with pytest.raises(ValueError):
         kernel.gf_encode_mxu_kernel(M, jnp.zeros((2, 3), jnp.int32), 8,
-                                    block=2)
+                                    block=2, interpret=True)
 
 
 def test_bitlift_matrix_rank():

@@ -287,13 +287,20 @@ def test_degraded_read_hot_tier(tmp_path):
        ln=st.integers(min_value=0, max_value=600),
        lost=st.sets(st.integers(min_value=0, max_value=7), max_size=4))
 def test_degraded_read_property(off, ln, lost):
-    """Any byte range, any tolerable loss set: degraded == plain read."""
+    """Any byte range, any loss of at most n-k shards: degraded == plain
+    read when the survivors decode, a clear FileNotFoundError when they do
+    not (RapidRAID is not MDS, so a few n-k loss sets are fatal)."""
     with tempfile.TemporaryDirectory() as tmp:
         store, blocks = _archived_store(tmp)
         blob = blocks[1].reshape(-1).tobytes()
         for i in lost:
             store.fail_node(i)
         ln_c = min(ln, 4 * 512 - off)
+        survivors = [p for p in range(ACFG.n) if p not in lost]
+        if ln_c and not ACFG.code().decodable(survivors):
+            with pytest.raises(FileNotFoundError):
+                arc.read_range(store, 1, ACFG, off, ln_c)
+            return
         assert arc.read_range(store, 1, ACFG, off, ln_c) == \
             blob[off:off + ln_c]
 
