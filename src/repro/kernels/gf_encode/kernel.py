@@ -94,6 +94,7 @@ def gf_encode_kernel(M: np.ndarray, data_packed: jax.Array, l: int,
         out_specs=pl.BlockSpec((1, rows, block), lambda o, i: (o, 0, i)),
         out_shape=jax.ShapeDtypeStruct((O, rows, Bp), jnp.uint32),
         interpret=interpret,
+        name="gf_encode",
     )(data_packed)
     return out[0] if single else out
 
@@ -149,6 +150,7 @@ def chain_step_kernel(x_in: jax.Array, local: jax.Array, bp_psi: jax.Array,
             jax.ShapeDtypeStruct((O, 1, C), jnp.uint32),
         ],
         interpret=interpret,
+        name="chain_step",
     )(x_in, local, bp_psi, bp_xi)
     return (c[0], xo[0]) if single else (c, xo)
 
@@ -196,6 +198,7 @@ def repair_step_kernel(x_in: jax.Array, local: jax.Array, bp: jax.Array,
         out_specs=pl.BlockSpec((1, rows, block), lambda o, i: (o, 0, i)),
         out_shape=jax.ShapeDtypeStruct((O, rows, C), jnp.uint32),
         interpret=interpret,
+        name="repair_step",
     )(x_in, local, bp)
     return out[0] if single else out
 
@@ -264,4 +267,5 @@ def gf_encode_mxu_kernel(M: np.ndarray, data_words: jax.Array, l: int,
         out_specs=pl.BlockSpec((rows, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rows, B), jnp.int32),
         interpret=interpret,
+        name="gf_encode_mxu",
     )(data_words, jnp.asarray(Mbits))

@@ -1,0 +1,52 @@
+"""Host spans of the storage verbs, on the profiler's clock (leaf module).
+
+``span(name, **args)`` is a ``jax.profiler.TraceAnnotation``: inside a
+profiler session it writes a host event into the same trace as the device
+planes, so a device's idle gaps can be set against what the host was
+doing; outside one it costs about a microsecond. The keyword ``args``
+become the event's stats, which the profile viewer shows beside the span;
+they are counters for operators, not inputs to any computation.
+
+Every span the program emits: name, site, args.
+
+* ``manifest``: ``archive.get_manifest`` and ``archive._put_manifest``.
+* ``hot_load``: ``archive._hot_load_ex``, around its store calls, digests
+  and row copies; ``bytes``.
+* ``sha256``: ``object_store.digest``, which every digest goes through;
+  ``bytes``.
+* ``host_copy``: host-side copies of payload: hot rows into the object
+  (``_hot_load_ex``), the message stack (``_fused_encode``), coded rows to
+  blobs (``archive_step``), the helper stack (``repair_many``), repaired
+  rows to blobs (``_place_repaired``), range slices and decoded bytes
+  (``read_range_ex``); ``bytes``.
+* ``h2d``: ``jnp.asarray`` of the coding kernel's input (``_fused_encode``,
+  ``repair_many``); ``bytes``.
+* ``kernel_launch``: the coding kernel's dispatch with its pack and unpack;
+  host side, it returns before the device ends; ``kernel``.
+* ``d2h``: ``np.asarray`` of the kernel's result, which waits for the
+  device and then copies; ``bytes``.
+* ``reclaim``: the hot-replica deletes of ``archive_step``.
+* ``repair_plan``: ``code.repair_helpers`` (``_repair_state``) and
+  ``fault_tolerance.repair_plan`` (``repair_many``).
+* ``place_repaired``: ``archive._place_repaired``.
+* ``read_plan``: ``read_range_ex``'s alive probe, helper choice and decode
+  matrix.
+* ``read_decode``: ``gf_matmul_np`` per touched block (``read_range_ex``);
+  ``bytes``.
+* ``store.<call>``: ``NodeStore.put``, ``get``, ``get_range``, ``has``,
+  ``delete``, ``put_stream`` (opening, each write, the publish) and
+  ``get_stream`` (each frame); ``bytes`` where known.
+
+No span is named after a client verb (``archive``, ``repair``,
+``read_range``): a caller that times the verbs marks them under those
+names. No span nests inside another of its own name, except a
+``store.<call>`` inside a store subclass's own ``store.<call>``.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` with ``args`` as its stats; use as ``with``."""
+    return jax.profiler.TraceAnnotation(name, **args)

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import classical, codes, fault_tolerance, gf, rapidraid, streaming
+from repro.spans import span
 from repro.storage import chain as chain_lib
 from repro.storage import multi as multi_lib
 from repro.storage import repair as repair_lib
@@ -159,22 +160,24 @@ def _hot_load_ex(store: NodeStore, step: int,
     """(blocks, replica nodes actually read) — the node-tracking core of
     ``hot_load`` that ``restore_blocks_ex`` builds its ReadResult from."""
     k, B = manifest["k"], manifest["block_bytes"]
-    out = np.zeros((k, B), dtype=np.uint8)
-    touched: list[int] = []
-    for j in range(k):
-        holders = [i for i, held in enumerate(manifest["placement"])
-                   if j in held]
-        for node in holders:
-            rel = HOT.format(step=step, j=j)
-            if store.has(node, rel):
-                raw = store.get(node, rel)
-                if digest(raw) == manifest["digests"][j]:
-                    out[j] = np.frombuffer(raw, dtype=np.uint8)
-                    touched.append(node)
-                    break
-        else:
-            raise FileNotFoundError(
-                f"hot block {j} of step {step} lost on all replicas")
+    with span("hot_load", bytes=k * B):
+        out = np.zeros((k, B), dtype=np.uint8)
+        touched: list[int] = []
+        for j in range(k):
+            holders = [i for i, held in enumerate(manifest["placement"])
+                       if j in held]
+            for node in holders:
+                rel = HOT.format(step=step, j=j)
+                if store.has(node, rel):
+                    raw = store.get(node, rel)
+                    if digest(raw) == manifest["digests"][j]:
+                        with span("host_copy", bytes=B):
+                            out[j] = np.frombuffer(raw, dtype=np.uint8)
+                        touched.append(node)
+                        break
+            else:
+                raise FileNotFoundError(
+                    f"hot block {j} of step {step} lost on all replicas")
     return out, touched
 
 
@@ -297,17 +300,19 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
             order=_device_order(perm, sched is not None)))
     else:
         coded_w = _fused_encode(code, data_w[None], acfg.l)[0]
-    coded = _u8(coded_w)
-    coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
+    with span("host_copy", bytes=coded_w.nbytes):
+        coded = _u8(coded_w)
+        coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
 
     for pos in range(acfg.n):
         store.put(int(perm[pos]), ARC.format(step=step, i=pos),
                   coded_blobs[pos])
     if reclaim_hot:
         # drop the hot replicas (the actual capacity saving: 2x -> n/k)
-        for node, held in enumerate(manifest["placement"]):
-            for j in held:
-                store.delete(node, HOT.format(step=step, j=j))
+        with span("reclaim"):
+            for node, held in enumerate(manifest["placement"]):
+                for j in held:
+                    store.delete(node, HOT.format(step=step, j=j))
 
     manifest = {
         **manifest, "tier": "archive", "family": acfg.family,
@@ -442,8 +447,14 @@ def _fused_encode(code, objs_w: np.ndarray, l: int) -> np.ndarray:
     family encodes through the same fused GF kernel.
     """
     from repro.kernels.gf_encode import ops as kernel_ops
-    msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
-    rows = np.asarray(kernel_ops.encode_auto(code.G, jnp.asarray(msgs), l))
+    with span("host_copy", bytes=objs_w.nbytes):
+        msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
+    with span("h2d", bytes=msgs.nbytes):
+        msgs_dev = jnp.asarray(msgs)
+    with span("kernel_launch", kernel="encode_auto"):
+        rows_dev = kernel_ops.encode_auto(code.G, msgs_dev, l)
+    with span("d2h", bytes=rows_dev.nbytes):
+        rows = np.asarray(rows_dev)
     return rows.reshape(len(objs_w), code.n, -1)
 
 
@@ -803,23 +814,25 @@ def _place_repaired(store: NodeStore, step: int, manifest: dict,
     Verification precedes every write, so a miscomputed repair raises
     ValueError without installing a single block or touching the manifest.
     """
-    blobs = []
-    for r, pos in enumerate(missing):
-        blob = repaired[r].tobytes()
-        if digest(blob) != manifest["coded_digests"][pos]:
-            raise ValueError(
-                f"repair of codeword row {pos} does not match the archived "
-                f"digest — refusing to install")
-        blobs.append(blob)
-    perm = list(manifest["perm"])
-    for pos, blob in zip(missing, blobs):
-        node = perm[pos]
-        if replacement_nodes and pos in replacement_nodes:
-            node = replacement_nodes[pos]
-            perm[pos] = node
-        store.put(node, ARC.format(step=step, i=pos), blob)
-    manifest["perm"] = perm
-    _put_manifest(store, step, manifest)
+    with span("place_repaired"):
+        blobs = []
+        for r, pos in enumerate(missing):
+            with span("host_copy", bytes=repaired[r].nbytes):
+                blob = repaired[r].tobytes()
+            if digest(blob) != manifest["coded_digests"][pos]:
+                raise ValueError(
+                    f"repair of codeword row {pos} does not match the "
+                    f"archived digest — refusing to install")
+            blobs.append(blob)
+        perm = list(manifest["perm"])
+        for pos, blob in zip(missing, blobs):
+            node = perm[pos]
+            if replacement_nodes and pos in replacement_nodes:
+                node = replacement_nodes[pos]
+                perm[pos] = node
+            store.put(node, ARC.format(step=step, i=pos), blob)
+        manifest["perm"] = perm
+        _put_manifest(store, step, manifest)
 
 
 def _repair_state(store: NodeStore, step: int,
@@ -842,7 +855,8 @@ def _repair_state(store: NodeStore, step: int,
         if not missing:
             return [], [], []
         alive = [p for p in range(manifest["n"]) if p not in dead]
-        helpers = code.repair_helpers(missing, alive)
+        with span("repair_plan"):
+            helpers = code.repair_helpers(missing, alive)
         for h in helpers:
             if h not in raws:
                 raws[h] = store.get(perm[h], ARC.format(step=step, i=h))
@@ -930,10 +944,12 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
             continue
         l = manifests[grp[0]]["l"]
         code = _manifest_code(manifests[grp[0]])
-        shards_w = np.stack([
-            _words(np.stack([np.frombuffer(raw, dtype=np.uint8)
-                             for raw in state[s][2]]), l)
-            for s in grp])                      # (B_obj, |helpers|, B)
+        with span("host_copy",
+                  bytes=sum(len(raw) for s in grp for raw in state[s][2])):
+            shards_w = np.stack([
+                _words(np.stack([np.frombuffer(raw, dtype=np.uint8)
+                                 for raw in state[s][2]]), l)
+                for s in grp])                  # (B_obj, |helpers|, B)
         if not code.positionwise:
             # sub-packetized repair (regenerating codes): per-object host
             # combine of the beta-sub-block helper summands
@@ -966,10 +982,15 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
             else:
                 # helpers is already the plan's decodable helper set, so
                 # the plan over it returns the same set and an aligned R
-                _, R = fault_tolerance.repair_plan(code, missing, helpers)
-                packed = gf.pack_u32(jnp.asarray(shards_w), l)
-                fused = kernel_ops.encode_packed(R, packed, l)
-                repaired_w = np.asarray(gf.unpack_u32(fused, l))
+                with span("repair_plan"):
+                    _, R = fault_tolerance.repair_plan(code, missing, helpers)
+                with span("h2d", bytes=shards_w.nbytes):
+                    shards_dev = jnp.asarray(shards_w)
+                with span("kernel_launch", kernel="encode_packed"):
+                    repaired_dev = gf.unpack_u32(kernel_ops.encode_packed(
+                        R, gf.pack_u32(shards_dev, l), l), l)
+                with span("d2h", bytes=repaired_dev.nbytes):
+                    repaired_w = np.asarray(repaired_dev)
         for b, step in enumerate(grp):
             _place_repaired(store, step, manifests[step], missing,
                             _u8(repaired_w[b]), replacement_nodes)
@@ -1084,25 +1105,27 @@ def read_range_ex(store: NodeStore, step: int, acfg: ArchiveConfig,
                 raise
         manifest = get_manifest(store, step)
         perm = manifest["perm"]
-    alive_ids = [pos for pos in range(manifest["n"])
-                 if store.has(perm[pos], ARC.format(step=step, i=pos))]
-    try:
-        chosen = codes.independent_rows(code.G[alive_ids], k, l)
-    except ValueError as e:
-        if manifest.get("hot_retained"):
-            # two-phase migration window: survivors are not decodable but
-            # the replicas were never reclaimed — the hot tier still backs
-            # the object (same fallback as restore_blocks_ex)
-            out, nodes = _hot_range(store, step, manifest, offset, end)
-            return _result(out, "hot", nodes, healed, step)
-        raise FileNotFoundError(
-            f"step {step}: survivors not decodable ({e})") from None
-    helpers = [alive_ids[p] for p in chosen]
+    with span("read_plan"):
+        alive_ids = [pos for pos in range(manifest["n"])
+                     if store.has(perm[pos], ARC.format(step=step, i=pos))]
+        try:
+            chosen = codes.independent_rows(code.G[alive_ids], k, l)
+        except ValueError as e:
+            if manifest.get("hot_retained"):
+                # two-phase migration window: survivors are not decodable
+                # but the replicas were never reclaimed — the hot tier
+                # still backs the object (same fallback as
+                # restore_blocks_ex)
+                out, nodes = _hot_range(store, step, manifest, offset, end)
+                return _result(out, "hot", nodes, healed, step)
+            raise FileNotFoundError(
+                f"step {step}: survivors not decodable ({e})") from None
+        helpers = [alive_ids[p] for p in chosen]
 
-    # per touched block: read ONLY its word-aligned slice of each helper
-    # shard and apply that block's row of the decode matrix
-    # (degraded_read_np's math with D hoisted out of the loop)
-    D = code.decode_matrix(helpers)
+        # per touched block: read ONLY its word-aligned slice of each helper
+        # shard and apply that block's row of the decode matrix
+        # (degraded_read_np's math with D hoisted out of the loop)
+        D = code.decode_matrix(helpers)
     wb = l // 8
     dt = gf.WORD_DTYPE[l]
     out = bytearray()
@@ -1111,16 +1134,19 @@ def read_range_ex(store: NodeStore, step: int, acfg: ArchiveConfig,
         b = min(end, (j + 1) * B) - j * B
         lo = (a // wb) * wb
         hi = -(-b // wb) * wb
-        slices_w = np.stack([
-            np.frombuffer(
-                store.get_range(perm[h], ARC.format(step=step, i=h),
-                                lo, hi - lo), dtype=np.uint8).view(dt)
-            for h in helpers])
-        row = _u8(gf.gf_matmul_np(D[[j]], slices_w, l))[0]
-        out += row[a - lo:b - lo].tobytes()
+        raws = [store.get_range(perm[h], ARC.format(step=step, i=h),
+                                lo, hi - lo) for h in helpers]
+        with span("host_copy", bytes=len(helpers) * (hi - lo)):
+            slices_w = np.stack([np.frombuffer(raw, dtype=np.uint8).view(dt)
+                                 for raw in raws])
+        with span("read_decode", bytes=slices_w.nbytes):
+            row = _u8(gf.gf_matmul_np(D[[j]], slices_w, l))[0]
+        with span("host_copy", bytes=b - a):
+            out += row[a - lo:b - lo].tobytes()
     served = "coded" if len(alive_ids) == manifest["n"] else "degraded"
-    return _result(bytes(out), served, [perm[h] for h in helpers],
-                   healed, step)
+    with span("host_copy", bytes=len(out)):
+        data = bytes(out)
+    return _result(data, served, [perm[h] for h in helpers], healed, step)
 
 
 def publish_device_archive(store: NodeStore, step: int, acfg: ArchiveConfig,
@@ -1267,9 +1293,10 @@ def publish_streaming_archive(store: NodeStore, step: int,
 
 
 def _put_manifest(store: NodeStore, step: int, manifest: dict) -> None:
-    data = json.dumps(manifest).encode()
-    for i in range(store.n_nodes):
-        store.put(i, MANIFEST.format(step=step), data)
+    with span("manifest"):
+        data = json.dumps(manifest).encode()
+        for i in range(store.n_nodes):
+            store.put(i, MANIFEST.format(step=step), data)
 
 
 _REQUIRED_KEYS = ("step", "tier", "n", "k", "l", "seed", "block_bytes")
@@ -1325,14 +1352,15 @@ def get_manifest(store: NodeStore, step: int) -> dict:
     rel = MANIFEST.format(step=step)
     errors: list[str] = []
     found = False
-    for i in range(store.n_nodes):
-        if not store.has(i, rel):
-            continue
-        found = True
-        try:
-            return _validate_manifest(json.loads(store.get(i, rel)), step)
-        except ValueError as e:           # JSONDecodeError is a ValueError
-            errors.append(f"node {i}: {e}")
+    with span("manifest"):
+        for i in range(store.n_nodes):
+            if not store.has(i, rel):
+                continue
+            found = True
+            try:
+                return _validate_manifest(json.loads(store.get(i, rel)), step)
+            except ValueError as e:       # JSONDecodeError is a ValueError
+                errors.append(f"node {i}: {e}")
     if found:
         raise ValueError(
             f"step {step}: every manifest replica is corrupt — "

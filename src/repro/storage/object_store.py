@@ -19,6 +19,8 @@ import shutil
 
 import numpy as np
 
+from repro.spans import span
+
 MAGIC = b"RRCK"
 
 
@@ -151,21 +153,23 @@ class NodeStore:
         return os.path.join(self.node_dir(i), rel)
 
     def put(self, i: int, rel: str, data: bytes) -> None:
-        p = self.path(i, rel)
-        os.makedirs(os.path.dirname(p), exist_ok=True)
-        tmp = p + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(data)
-        os.replace(tmp, p)  # atomic publish
+        with span("store.put", bytes=len(data)):
+            p = self.path(i, rel)
+            os.makedirs(os.path.dirname(p), exist_ok=True)
+            tmp = p + ".tmp"
+            with open(tmp, "wb") as f:
+                f.write(data)
+            os.replace(tmp, p)  # atomic publish
 
     def get(self, i: int, rel: str) -> bytes:
-        with open(self.path(i, rel), "rb") as f:
+        with span("store.get"), open(self.path(i, rel), "rb") as f:
             return f.read()
 
     def get_range(self, i: int, rel: str, offset: int, nbytes: int) -> bytes:
         """Read only [offset, offset+nbytes) of an object — the degraded-read
         primitive: a slice read costs the slice, not the block."""
-        with open(self.path(i, rel), "rb") as f:
+        with span("store.get_range", bytes=nbytes), \
+                open(self.path(i, rel), "rb") as f:
             f.seek(offset)
             return f.read(nbytes)
 
@@ -173,7 +177,8 @@ class NodeStore:
         return os.path.getsize(self.path(i, rel))
 
     def has(self, i: int, rel: str) -> bool:
-        return os.path.exists(self.path(i, rel))
+        with span("store.has"):
+            return os.path.exists(self.path(i, rel))
 
     def put_stream(self, i: int, rel: str) -> "StreamWriter":
         """Open a frame-at-a-time write; ``close()`` publishes atomically."""
@@ -187,15 +192,17 @@ class NodeStore:
                              f"got {frame_bytes}")
         with open(self.path(i, rel), "rb") as f:
             while True:
-                frame = f.read(frame_bytes)
+                with span("store.get_stream", bytes=frame_bytes):
+                    frame = f.read(frame_bytes)
                 if not frame:
                     return
                 yield frame
 
     def delete(self, i: int, rel: str) -> None:
-        p = self.path(i, rel)
-        if os.path.exists(p):
-            os.remove(p)
+        with span("store.delete"):
+            p = self.path(i, rel)
+            if os.path.exists(p):
+                os.remove(p)
 
     def fail_node(self, i: int) -> None:
         """Simulate a node loss: wipe its disk."""
@@ -222,14 +229,16 @@ class StreamWriter:
     def __init__(self, path: str):
         self._final = path
         self._tmp = path + ".tmp"
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        self._f = open(self._tmp, "wb")
+        with span("store.put_stream"):
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            self._f = open(self._tmp, "wb")
         self._sha = hashlib.sha256()
         self.nbytes = 0
 
     def write(self, frame: bytes) -> None:
-        self._f.write(frame)
-        self._sha.update(frame)
+        with span("store.put_stream", bytes=len(frame)):
+            self._f.write(frame)
+            self._sha.update(frame)
         self.nbytes += len(frame)
 
     def digest(self) -> str:
@@ -240,8 +249,9 @@ class StreamWriter:
         """Atomic publish: the object appears whole or not at all."""
         if self._f.closed:
             return
-        self._f.close()
-        os.replace(self._tmp, self._final)
+        with span("store.put_stream"):
+            self._f.close()
+            os.replace(self._tmp, self._final)
 
     def abort(self) -> None:
         """Drop the partial write; the target path is untouched."""
@@ -347,4 +357,5 @@ class ChurnNodeStore(NodeStore):
 
 
 def digest(data: bytes) -> str:
-    return hashlib.sha256(data).hexdigest()[:16]
+    with span("sha256", bytes=len(data)):
+        return hashlib.sha256(data).hexdigest()[:16]
