@@ -10,17 +10,22 @@ they are counters for operators, not inputs to any computation.
 Every span the program emits: name, site, args.
 
 * ``manifest``: ``archive.get_manifest`` and ``archive._put_manifest``.
-* ``hot_load``: ``archive._hot_load_ex``, around its store calls, digests
-  and row copies; ``bytes``.
+* ``hot_load``: ``archive._hot_load_ex`` (store calls, digests, row
+  copies) and ``archive._hot_rows`` (store calls, digests); ``bytes``.
 * ``sha256``: ``object_store.digest``, which every digest goes through;
   ``bytes``.
-* ``host_copy``: host-side copies of payload: hot rows into the object
-  (``_hot_load_ex``), the message stack (``_fused_encode``), coded rows to
-  blobs (``archive_step``), the helper stack (``repair_many``), repaired
-  rows to blobs (``_place_repaired``), range slices and decoded bytes
-  (``read_range_ex``); ``bytes``.
-* ``h2d``: ``jnp.asarray`` of the coding kernel's input (``_fused_encode``,
-  ``repair_many``); ``bytes``.
+* ``host_copy``: host-side copies of payload, where the payload must sit
+  in one host array: hot rows into the object (``_hot_load_ex``), rows
+  gathered for a sub-packetized code or a device mesh (``_gather``), the
+  sub-packetized message (``_fused_encode``), range slices and decoded
+  bytes (``read_range_ex``); ``bytes``. The fused archive and repair of a
+  positionwise code copy nothing: coded and repaired rows go to the store
+  as views.
+* ``h2d``: the coding kernel's input sent to the device, one span per
+  launch (``_to_device``, or ``_fused_encode``'s ``jnp.asarray`` of a
+  sub-packetized message); ``bytes`` in all, and ``direct``, the store
+  buffers sent as they are, with no host copy (k per archived object,
+  the helpers per repaired one; 0 for a sub-packetized message).
 * ``kernel_launch``: the coding kernel's dispatch with its pack and unpack;
   host side, it returns before the device ends; ``kernel``.
 * ``d2h``: ``np.asarray`` of the kernel's result, which waits for the

@@ -37,6 +37,7 @@ mapping so decode is permutation-aware.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 
@@ -124,6 +125,13 @@ def _u8(blocks_w: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(blocks_w).view(np.uint8)
 
 
+def _rows(blocks_u8: np.ndarray) -> list[memoryview]:
+    """Each row of a C-contiguous (rows, B) uint8 array (what ``_u8``
+    returns) as a memoryview, which ``store.put`` and ``digest`` take in
+    place, with no copy."""
+    return [memoryview(row) for row in blocks_u8]
+
+
 # ---------------------------------------------------------------------------
 # hot tier (replicated per RapidRAID placement)
 # ---------------------------------------------------------------------------
@@ -155,6 +163,24 @@ def hot_load(store: NodeStore, step: int, manifest: dict) -> np.ndarray:
     return _hot_load_ex(store, step, manifest)[0]
 
 
+def _verified_hot(store: NodeStore, step: int, manifest: dict):
+    """Yield (raw, node) for each hot block in order: the bytes of the first
+    replica holder whose copy matches the manifest digest."""
+    for j in range(manifest["k"]):
+        holders = [i for i, held in enumerate(manifest["placement"])
+                   if j in held]
+        for node in holders:
+            rel = HOT.format(step=step, j=j)
+            if store.has(node, rel):
+                raw = store.get(node, rel)
+                if digest(raw) == manifest["digests"][j]:
+                    yield raw, node
+                    break
+        else:
+            raise FileNotFoundError(
+                f"hot block {j} of step {step} lost on all replicas")
+
+
 def _hot_load_ex(store: NodeStore, step: int,
                  manifest: dict) -> tuple[np.ndarray, list[int]]:
     """(blocks, replica nodes actually read) — the node-tracking core of
@@ -163,22 +189,22 @@ def _hot_load_ex(store: NodeStore, step: int,
     with span("hot_load", bytes=k * B):
         out = np.zeros((k, B), dtype=np.uint8)
         touched: list[int] = []
-        for j in range(k):
-            holders = [i for i, held in enumerate(manifest["placement"])
-                       if j in held]
-            for node in holders:
-                rel = HOT.format(step=step, j=j)
-                if store.has(node, rel):
-                    raw = store.get(node, rel)
-                    if digest(raw) == manifest["digests"][j]:
-                        with span("host_copy", bytes=B):
-                            out[j] = np.frombuffer(raw, dtype=np.uint8)
-                        touched.append(node)
-                        break
-            else:
-                raise FileNotFoundError(
-                    f"hot block {j} of step {step} lost on all replicas")
+        for j, (raw, node) in enumerate(_verified_hot(store, step, manifest)):
+            with span("host_copy", bytes=B):
+                out[j] = np.frombuffer(raw, dtype=np.uint8)
+            touched.append(node)
     return out, touched
+
+
+def _hot_rows(store: NodeStore, step: int, manifest: dict,
+              l: int) -> list[np.ndarray]:
+    """The k digest-verified hot blocks as GF(2^l) word views of the
+    store's own buffers, with no copy: what the fused encode sends to the
+    device."""
+    k, B = manifest["k"], manifest["block_bytes"]
+    with span("hot_load", bytes=k * B):
+        return [np.frombuffer(raw, dtype=gf.WORD_DTYPE[l])
+                for raw, _ in _verified_hot(store, step, manifest)]
 
 
 # ---------------------------------------------------------------------------
@@ -283,12 +309,11 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
                 use_devices, reclaim_hot)
         # plan degenerated to one stripe: the monolithic path IS the stream
 
-    blocks = hot_load(store, step, manifest)
-    data_w = _words(blocks, acfg.l)
     # largest feasible chunk count: every chunk must be whole uint32 lanes
     # (the device chain's granularity; the host oracle only needs nc | B,
     # which the stricter condition implies)
-    while nc > 1 and data_w.shape[1] % (gf.LANES[acfg.l] * nc):
+    words = manifest["block_bytes"] // (acfg.l // 8)
+    while nc > 1 and words % (gf.LANES[acfg.l] * nc):
         nc //= 2
     if sched is not None:
         sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
@@ -296,13 +321,12 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
         use_devices = len(jax.devices()) >= acfg.n
     if use_devices and code.supports_chain_encode:
         coded_w = np.asarray(chain_lib.pipelined_encode(
-            code, data_w, num_chunks=nc,
-            order=_device_order(perm, sched is not None)))
+            code, _words(hot_load(store, step, manifest), acfg.l),
+            num_chunks=nc, order=_device_order(perm, sched is not None)))
     else:
-        coded_w = _fused_encode(code, data_w[None], acfg.l)[0]
-    with span("host_copy", bytes=coded_w.nbytes):
-        coded = _u8(coded_w)
-        coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
+        coded_w = _fused_encode(
+            code, [_hot_rows(store, step, manifest, acfg.l)], acfg.l)[0]
+    coded_blobs = _rows(_u8(coded_w))
 
     for pos in range(acfg.n):
         store.put(int(perm[pos]), ARC.format(step=step, i=pos),
@@ -438,19 +462,55 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     return manifest
 
 
-def _fused_encode(code, objs_w: np.ndarray, l: int) -> np.ndarray:
-    """(O, k, B) object words -> (O, n, B) codeword words in ONE fused
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_rows(rows: list[jax.Array], objects: int) -> jax.Array:
+    """O * R device rows of W words -> one (O, R, W) array, on the device."""
+    return jnp.stack(rows).reshape(objects, len(rows) // objects, -1)
+
+
+def _to_device(objs) -> jax.Array:
+    """O sequences of R word rows -> (O, R, W) on the default device.
+
+    Each row (a view of a store buffer) is sent as it is and the rows are
+    stacked on the device, so no payload byte is copied on the host; the
+    ``h2d`` span's ``direct`` counts the rows sent so.
+    """
+    rows = [row for obj in objs for row in obj]
+    with span("h2d", bytes=sum(r.nbytes for r in rows), direct=len(rows)):
+        return _stack_rows(jax.device_put(rows), len(objs))
+
+
+def _gather(objs) -> np.ndarray:
+    """O sequences of R word rows -> one (O, R, W) host array, filled once
+    (the paths that need the payload on the host)."""
+    first = objs[0][0]
+    out = np.empty((len(objs), len(objs[0]), len(first)), first.dtype)
+    with span("host_copy", bytes=out.nbytes):
+        for o, obj in enumerate(objs):
+            for r, row in enumerate(obj):
+                out[o, r] = row
+    return out
+
+
+def _fused_encode(code, objs_w, l: int) -> np.ndarray:
+    """O objects of k word rows -> (O, n, B) codeword words in ONE fused
     kernel launch on the default device, the object axis on the kernel grid.
 
-    The message view is the identity for positionwise codes and the
-    sub-packetized (M_sub, W) layout for regenerating codes, so EVERY
-    family encodes through the same fused GF kernel.
+    ``objs_w`` is O sequences of k 1-D word rows. The message view is the
+    identity for positionwise codes, whose rows go to the device as they
+    are (``_to_device``), and the sub-packetized (M_sub, W) layout for
+    regenerating codes, built on the host, so EVERY family encodes through
+    the same fused GF kernel.
     """
     from repro.kernels.gf_encode import ops as kernel_ops
-    with span("host_copy", bytes=objs_w.nbytes):
-        msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
-    with span("h2d", bytes=msgs.nbytes):
-        msgs_dev = jnp.asarray(msgs)
+    if code.positionwise:
+        msgs_dev = _to_device(objs_w)
+    else:
+        objs_w = _gather(objs_w)
+        with span("host_copy", bytes=objs_w.nbytes):
+            msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
+        with span("h2d", bytes=msgs.nbytes, direct=0):
+            msgs_dev = jnp.asarray(msgs)
     with span("kernel_launch", kernel="encode_auto"):
         rows_dev = kernel_ops.encode_auto(code.G, msgs_dev, l)
     with span("d2h", bytes=rows_dev.nbytes):
@@ -467,9 +527,8 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
     hot steps and place/manifest the coded blocks."""
     # blocks are loaded one group at a time (and released after the
     # group's encode) so peak host memory is one group, not the batch
-    objs_w = np.stack([_words(hot_load(store, s, manifests[s]), acfg.l)
-                       for s in grp])
-    B = objs_w.shape[-1]
+    rows = [_hot_rows(store, s, manifests[s], acfg.l) for s in grp]
+    B = manifests[grp[0]]["block_bytes"] // (acfg.l // 8)
     nc = num_chunks
     while nc > 1 and B % (gf.LANES[acfg.l] * nc):
         nc //= 2
@@ -477,14 +536,14 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
         sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
     if use_devices and code.supports_chain_encode:
         coded_w = np.asarray(multi_lib.pipelined_encode_many(
-            code, objs_w, num_chunks=nc, stagger=stagger,
+            code, _gather(rows), num_chunks=nc, stagger=stagger,
             order=_device_order(perm, sched is not None)))
     else:
-        coded_w = _fused_encode(code, objs_w, acfg.l)
+        coded_w = _fused_encode(code, rows, acfg.l)
+    del rows
     out: dict[int, dict] = {}
     for b, step in enumerate(grp):
-        coded = _u8(coded_w[b])
-        coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
+        coded_blobs = _rows(_u8(coded_w[b]))
         for pos in range(acfg.n):
             store.put(int(perm[pos]), ARC.format(step=step, i=pos),
                       coded_blobs[pos])
@@ -815,15 +874,12 @@ def _place_repaired(store: NodeStore, step: int, manifest: dict,
     ValueError without installing a single block or touching the manifest.
     """
     with span("place_repaired"):
-        blobs = []
-        for r, pos in enumerate(missing):
-            with span("host_copy", bytes=repaired[r].nbytes):
-                blob = repaired[r].tobytes()
+        blobs = _rows(repaired)
+        for blob, pos in zip(blobs, missing):
             if digest(blob) != manifest["coded_digests"][pos]:
                 raise ValueError(
                     f"repair of codeword row {pos} does not match the "
                     f"archived digest — refusing to install")
-            blobs.append(blob)
         perm = list(manifest["perm"])
         for pos, blob in zip(missing, blobs):
             node = perm[pos]
@@ -944,53 +1000,48 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
             continue
         l = manifests[grp[0]]["l"]
         code = _manifest_code(manifests[grp[0]])
-        with span("host_copy",
-                  bytes=sum(len(raw) for s in grp for raw in state[s][2])):
-            shards_w = np.stack([
-                _words(np.stack([np.frombuffer(raw, dtype=np.uint8)
-                                 for raw in state[s][2]]), l)
-                for s in grp])                  # (B_obj, |helpers|, B)
+        # per object, its helpers' verified buffers as word views (no copy)
+        rows = [[np.frombuffer(raw, dtype=gf.WORD_DTYPE[l])
+                 for raw in state[s][2]] for s in grp]
+        on_mesh = (len(jax.devices()) >= len(helpers)
+                   if use_devices is None else use_devices)
         if not code.positionwise:
             # sub-packetized repair (regenerating codes): per-object host
             # combine of the beta-sub-block helper summands
+            shards_w = _gather(rows)
             repaired_w = np.stack([
                 code.repair_np(missing, helpers, shards_w[b])
                 for b in range(len(grp))])
-        else:
-            if use_devices is None:
-                use_devices_grp = len(jax.devices()) >= len(helpers)
+        elif on_mesh:
+            shards_w = _gather(rows)            # (B_obj, |helpers|, B)
+            nc = acfg.num_chunks
+            sc_words = None
+            wb = l // 8
+            if superchunk_bytes is not None:
+                sc_words = max(1, superchunk_bytes // wb)
             else:
-                use_devices_grp = use_devices
-            if use_devices_grp:
-                nc = acfg.num_chunks
+                stream = manifests[grp[0]].get("streaming")
+                if stream:              # heal with the archive's geometry
+                    sc_words = stream["superchunk_bytes"] // wb
+            if sc_words is None or sc_words >= shards_w.shape[-1]:
+                # identity plan: the monolithic chunking rules apply
                 sc_words = None
-                wb = l // 8
-                if superchunk_bytes is not None:
-                    sc_words = max(1, superchunk_bytes // wb)
-                else:
-                    stream = manifests[grp[0]].get("streaming")
-                    if stream:          # heal with the archive's geometry
-                        sc_words = stream["superchunk_bytes"] // wb
-                if sc_words is None or sc_words >= shards_w.shape[-1]:
-                    # identity plan: the monolithic chunking rules apply
-                    sc_words = None
-                    while nc > 1 and shards_w.shape[-1] % (gf.LANES[l] * nc):
-                        nc //= 2
-                repaired_w = np.asarray(repair_lib.pipelined_repair_many(
-                    code, helpers, shards_w, missing, num_chunks=nc,
-                    stagger=stagger, superchunk_words=sc_words))
-            else:
-                # helpers is already the plan's decodable helper set, so
-                # the plan over it returns the same set and an aligned R
-                with span("repair_plan"):
-                    _, R = fault_tolerance.repair_plan(code, missing, helpers)
-                with span("h2d", bytes=shards_w.nbytes):
-                    shards_dev = jnp.asarray(shards_w)
-                with span("kernel_launch", kernel="encode_packed"):
-                    repaired_dev = gf.unpack_u32(kernel_ops.encode_packed(
-                        R, gf.pack_u32(shards_dev, l), l), l)
-                with span("d2h", bytes=repaired_dev.nbytes):
-                    repaired_w = np.asarray(repaired_dev)
+                while nc > 1 and shards_w.shape[-1] % (gf.LANES[l] * nc):
+                    nc //= 2
+            repaired_w = np.asarray(repair_lib.pipelined_repair_many(
+                code, helpers, shards_w, missing, num_chunks=nc,
+                stagger=stagger, superchunk_words=sc_words))
+        else:
+            # helpers is already the plan's decodable helper set, so the
+            # plan over it returns the same set and an aligned R
+            with span("repair_plan"):
+                _, R = fault_tolerance.repair_plan(code, missing, helpers)
+            shards_dev = _to_device(rows)       # (B_obj, |helpers|, B)
+            with span("kernel_launch", kernel="encode_packed"):
+                repaired_dev = gf.unpack_u32(kernel_ops.encode_packed(
+                    R, gf.pack_u32(shards_dev, l), l), l)
+            with span("d2h", bytes=repaired_dev.nbytes):
+                repaired_w = np.asarray(repaired_dev)
         for b, step in enumerate(grp):
             _place_repaired(store, step, manifests[step], missing,
                             _u8(repaired_w[b]), replacement_nodes)

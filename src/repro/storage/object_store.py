@@ -152,7 +152,11 @@ class NodeStore:
     def path(self, i: int, rel: str) -> str:
         return os.path.join(self.node_dir(i), rel)
 
-    def put(self, i: int, rel: str, data: bytes) -> None:
+    def put(self, i: int, rel: str, data: bytes | memoryview) -> None:
+        """Publish ``data`` at ``rel`` on node i, atomically. ``data`` is
+        any C-contiguous bytes-like object of single bytes (``bytes``, a
+        memoryview of a uint8 row); it is written as it is and no
+        reference to it is kept."""
         with span("store.put", bytes=len(data)):
             p = self.path(i, rel)
             os.makedirs(os.path.dirname(p), exist_ok=True)
@@ -322,7 +326,7 @@ class ChurnNodeStore(NodeStore):
     def is_up(self, i: int) -> bool:
         return i not in self.down
 
-    def put(self, i: int, rel: str, data: bytes) -> None:
+    def put(self, i: int, rel: str, data: bytes | memoryview) -> None:
         if i in self.down:
             return                      # write addressed to a dead node: lost
         super().put(i, rel, data)
@@ -356,6 +360,6 @@ class ChurnNodeStore(NodeStore):
         return i not in self.down and super().has(i, rel)
 
 
-def digest(data: bytes) -> str:
+def digest(data: bytes | memoryview) -> str:
     with span("sha256", bytes=len(data)):
         return hashlib.sha256(data).hexdigest()[:16]

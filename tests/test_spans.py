@@ -28,12 +28,12 @@ VERBS = ("archive", "repair", "read_range")
 
 #: the spans each verb must hold (``repro.spans``)
 EXPECTED = {
-    "archive": {"manifest", "hot_load", "sha256", "host_copy", "h2d",
-                "kernel_launch", "d2h", "reclaim", "store.has", "store.get",
-                "store.put", "store.delete"},
-    "repair": {"manifest", "repair_plan", "sha256", "host_copy", "h2d",
-               "kernel_launch", "d2h", "place_repaired", "store.has",
-               "store.get", "store.put"},
+    "archive": {"manifest", "hot_load", "sha256", "h2d", "kernel_launch",
+                "d2h", "reclaim", "store.has", "store.get", "store.put",
+                "store.delete"},
+    "repair": {"manifest", "repair_plan", "sha256", "h2d", "kernel_launch",
+               "d2h", "place_repaired", "store.has", "store.get",
+               "store.put"},
     "read_range": {"manifest", "read_plan", "read_decode", "host_copy",
                    "store.has", "store.get", "store.get_range"},
 }
@@ -135,6 +135,28 @@ def test_byte_counts_on_the_spans(traced):
     decode = [s for n, *_, s in _inside(events, "read_range")
               if n == "read_decode"]
     assert len(decode) == 2                    # one per touched block
+
+
+@pytest.mark.parametrize("verb", VERBS)
+def test_host_copies_only_where_payload_is_copied(traced, verb):
+    """The coding verbs of a positionwise code send the store's buffers to
+    the device and write the coded rows as views: no ``host_copy`` lies
+    inside them. A range read still slices and joins on the host."""
+    events, _, _ = traced
+    copies = [e for e in _inside(events, verb) if e[0] == "host_copy"]
+    assert bool(copies) == (verb == "read_range"), copies
+
+
+def test_h2d_sends_each_store_buffer_direct(traced):
+    """One ``h2d`` per launch; ``direct`` counts the store buffers sent
+    without a host copy: the k hot blocks, then the repair's helpers."""
+    events, _, _ = traced
+    helpers = ACFG.code().repair_helpers(
+        [LOST], [p for p in range(ACFG.n) if p != LOST])
+    for verb, rows in (("archive", ACFG.k), ("repair", len(helpers))):
+        (h2d,) = [s for n, *_, s in _inside(events, verb) if n == "h2d"]
+        assert h2d["direct"] == rows, verb
+        assert h2d["bytes"] == rows * BLOCK, verb
 
 
 def test_no_span_nests_in_its_own_name(traced):
