@@ -17,6 +17,7 @@ from repro.core.codes.registry import families, from_spec, make, register
 
 register("rapidraid", "repro.core.rapidraid:_make_canonical")
 register("lrc", "repro.core.codes.lrc:make")
+register("xorbas", "repro.core.codes.lrc:make_xorbas")
 register("mbr", "repro.core.codes.regenerating:make")
 
 __all__ = ["CodeSpec", "ErasureCode", "independent_rows",

@@ -44,6 +44,7 @@ from typing import Callable
 import numpy as np
 
 from repro.core import gf
+from repro.spans import span
 
 #: env knob used by CI to force a small per-device streaming budget; the
 #: tier-1 streaming leg runs the whole test module under a few MB.
@@ -157,6 +158,57 @@ def budget_from_env(default: int | None = None) -> int | None:
     return int(raw) if raw else default
 
 
+#: share of the device's memory limit that one fused archive encode may
+#: hold; a stripe that needs more is coded in device stripes
+DEVICE_BUDGET_SHARE = 0.5
+
+
+def fused_encode_bytes(k: int, n: int, words: int, l: int) -> int:
+    """Peak device bytes of one fused encode of k rows of ``words`` words
+    into n (``kernel_ops.encode_auto`` over rows stacked on the device).
+
+    The buffers live at once while the result is unpacked: the stacked
+    input and its packed lanes (2 k rows), the kernel's packed output, the
+    unpacked lane planes and their concatenation (3 n rows). On a TPU v5e
+    this gives 4,697,620,480 B for the (16,11) 64 MiB archive and
+    6,417,285,120 B for LRC(12,2,2) at 85 MiB against measured peaks of
+    4,699,773,952 and 6,419,522,048.
+    """
+    return (2 * k + 3 * n) * words * (l // 8)
+
+
+def device_budget() -> int | None:
+    """Device bytes a fused archive encode may hold: the forced budget
+    (``RAPIDRAID_STREAM_BUDGET_BYTES``) if set, else ``DEVICE_BUDGET_SHARE``
+    of the default device's ``bytes_limit``; None where the device reports
+    no limit (the CPU)."""
+    forced = budget_from_env()
+    if forced is not None:
+        return forced
+    import jax
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit * DEVICE_BUDGET_SHARE) if limit else None
+
+
+def fused_stripe_words(k: int, n: int, total_words: int, l: int,
+                       budget: int, granule: int) -> int | None:
+    """Stripe width for a fused archive encode of ``total_words`` a row
+    under ``budget`` device bytes; None where one launch fits.
+
+    Two stripes are in flight in ``execute`` (one coding, the one before
+    it retiring), so each may hold half the budget. Of the fewest stripes
+    that fit, all are equally wide, rounded up to ``granule`` words (a
+    whole number of kernel tiles); never below one granule.
+    """
+    if fused_encode_bytes(k, n, total_words, l) <= budget:
+        return None
+    per_word = 2 * fused_encode_bytes(k, n, 1, l)
+    widest = max(granule, budget // per_word // granule * granule)
+    stripes = -(-total_words // widest)
+    width = -(-total_words // stripes)
+    return -(-width // granule) * granule
+
+
 # ---------------------------------------------------------------------------
 # the double-buffered executor
 # ---------------------------------------------------------------------------
@@ -176,25 +228,52 @@ def execute(plan: StreamPlan, program: Callable,
     run on the devices the host is simultaneously reading stripe s+1's
     input (get) and writing stripe s-1's output (put) — the host never
     blocks on a device result until the window is full. Results are
-    retired strictly in stripe order.
+    retired strictly in stripe order. Each result's copy back starts as
+    soon as it is dispatched (``copy_to_host_async``), so it too overlaps
+    the host's next stripe. Per stripe, the transfer, the dispatch and the
+    wait for the copy back are the spans ``h2d``, ``kernel_launch`` and
+    ``d2h``; a stripe given as a sequence of arrays is sent array by
+    array (``h2d``'s ``direct`` counts them).
     """
     if depth < 1:
         raise ValueError(f"execute: depth must be >= 1, got {depth}")
     import jax
     pending: collections.deque = collections.deque()
+
+    def retire() -> None:
+        s0, y0 = pending.popleft()
+        with span("d2h", bytes=y0.nbytes):
+            out = np.asarray(y0)
+        put_stripe(s0, out)
+
     for s in range(plan.num_superchunks):
         x = get_stripe(s)
+        direct = len(x) if isinstance(x, (list, tuple)) else 0
         try:  # async h2d so the transfer overlaps the in-flight compute
-            x = jax.device_put(x)
+            with span("h2d", bytes=_nbytes(x), direct=direct):
+                x = jax.device_put(x)
         except (TypeError, ValueError):  # non-array inputs: let program cope
             pass
-        pending.append((s, program(x)))   # async dispatch
+        with span("kernel_launch", kernel=_name(program)):
+            y = program(x)                # async dispatch
+        y.copy_to_host_async()            # the copy back overlaps the host
+        pending.append((s, y))
+        del x, y
         while len(pending) > depth:
-            s0, y0 = pending.popleft()
-            put_stripe(s0, np.asarray(y0))
+            retire()
     while pending:
-        s0, y0 = pending.popleft()
-        put_stripe(s0, np.asarray(y0))
+        retire()
+
+
+def _nbytes(x) -> int:
+    """Payload bytes of one array or a sequence of them."""
+    if isinstance(x, (list, tuple)):
+        return sum(int(getattr(a, "nbytes", 0)) for a in x)
+    return int(getattr(x, "nbytes", 0))
+
+
+def _name(program) -> str:
+    return getattr(program, "__name__", type(program).__name__)
 
 
 def run_words(program: Callable, data: np.ndarray, plan: StreamPlan, *,

@@ -12,8 +12,8 @@ Every span the program emits: name, site, args.
 * ``manifest``: ``archive.get_manifest`` and ``archive._put_manifest``.
 * ``hot_load``: ``archive._hot_load_ex`` (store calls, digests, row
   copies) and ``archive._hot_rows`` (store calls, digests); ``bytes``.
-* ``sha256``: ``object_store.digest``, which every digest goes through;
-  ``bytes``.
+* ``sha256``: ``object_store.digest``, which every whole digest goes
+  through, and the streamed archive's incremental hot digests; ``bytes``.
 * ``host_copy``: host-side copies of payload, where the payload must sit
   in one host array: hot rows into the object (``_hot_load_ex``), rows
   gathered for a sub-packetized code or a device mesh (``_gather``), the
@@ -23,16 +23,26 @@ Every span the program emits: name, site, args.
   as views.
 * ``h2d``: the coding kernel's input sent to the device, one span per
   launch (``_to_device``, or ``_fused_encode``'s ``jnp.asarray`` of a
-  sub-packetized message); ``bytes`` in all, and ``direct``, the store
-  buffers sent as they are, with no host copy (k per archived object,
-  the helpers per repaired one; 0 for a sub-packetized message).
-* ``kernel_launch``: the coding kernel's dispatch with its pack and unpack;
-  host side, it returns before the device ends; ``kernel``.
+  sub-packetized message) and one per stripe (``streaming.execute``);
+  ``bytes`` in all, and ``direct``, the store buffers sent as they are,
+  with no host copy (k per archived object or archive stripe, the helpers
+  per repaired one; 0 for a sub-packetized message or a stripe assembled
+  on the host).
+* ``kernel_launch``: the coding kernel's dispatch with its pack and unpack,
+  per launch or per stripe; host side, it returns before the device ends;
+  ``kernel``.
 * ``d2h``: ``np.asarray`` of the kernel's result, which waits for the
-  device and then copies; ``bytes``.
+  device and then copies, per launch or per stripe (whose copy back was
+  started when it was dispatched); ``bytes``.
+* ``stripe_read``: one per stripe of a streamed archive
+  (``_archive_step_streaming``): the stripe's hot range reads and the
+  incremental hot digests (under ``sha256``); ``stripe``, ``bytes``.
+* ``stripe_write``: one per stripe of a streamed archive: the stripe's
+  framed coded writes and their per-stripe digests; ``stripe``, ``bytes``.
 * ``reclaim``: the hot-replica deletes of ``archive_step``.
 * ``repair_plan``: ``code.repair_helpers`` (``_repair_state``) and
-  ``fault_tolerance.repair_plan`` (``repair_many``).
+  ``fault_tolerance.repair_plan`` (``repair_many``); ``helpers``, the
+  rows the plan reads.
 * ``place_repaired``: ``archive._place_repaired``.
 * ``read_plan``: ``read_range_ex``'s alive probe, helper choice and decode
   matrix.

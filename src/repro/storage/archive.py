@@ -278,6 +278,12 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
     writes BEFORE anything is published. Sub-packetized families cannot
     stream (raises ValueError).
 
+    Without ``superchunk_bytes``, a positionwise code coded off the chain
+    streams by itself when one fused launch would hold more device memory
+    than ``streaming.device_budget`` allows
+    (``streaming.fused_encode_bytes``): it then codes in the fewest equal
+    stripes that fit (``streaming.fused_stripe_words``).
+
     ``reclaim_hot=False`` defers the replica deletion: the step is coded
     and readable from the archive tier, but the hot replicas stay on disk
     (manifest ``hot_retained``) until ``reclaim_replicas`` has digest-
@@ -293,11 +299,19 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
     perm, nc, sched = _plan_placement(acfg, manifest["block_bytes"],
                                       topology, node_speeds)
 
+    wb = acfg.l // 8
+    words = manifest["block_bytes"] // wb
+    if use_devices is None:
+        use_devices = len(jax.devices()) >= acfg.n
+    on_chain = use_devices and code.supports_chain_encode
+    sc_words = None
     if superchunk_bytes is not None:
-        wb = acfg.l // 8
-        plan = streaming.plan_stream(manifest["block_bytes"] // wb,
-                                     max(1, superchunk_bytes // wb),
-                                     l=acfg.l, num_chunks=nc)
+        sc_words = max(1, superchunk_bytes // wb)
+    elif code.positionwise and not on_chain:
+        sc_words = _fused_stripe_words(code, words)
+    if sc_words is not None:
+        plan = streaming.plan_stream(words, sc_words, l=acfg.l,
+                                     num_chunks=nc)
         if plan.streaming:
             if not code.positionwise:
                 raise ValueError(
@@ -306,20 +320,17 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
                     f"stream (archive without superchunk_bytes)")
             return _archive_step_streaming(
                 store, step, acfg, manifest, code, perm, nc, sched, plan,
-                use_devices, reclaim_hot)
+                on_chain, reclaim_hot)
         # plan degenerated to one stripe: the monolithic path IS the stream
 
     # largest feasible chunk count: every chunk must be whole uint32 lanes
     # (the device chain's granularity; the host oracle only needs nc | B,
     # which the stricter condition implies)
-    words = manifest["block_bytes"] // (acfg.l // 8)
     while nc > 1 and words % (gf.LANES[acfg.l] * nc):
         nc //= 2
     if sched is not None:
         sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
-    if use_devices is None:
-        use_devices = len(jax.devices()) >= acfg.n
-    if use_devices and code.supports_chain_encode:
+    if on_chain:
         coded_w = np.asarray(chain_lib.pipelined_encode(
             code, _words(hot_load(store, step, manifest), acfg.l),
             num_chunks=nc, order=_device_order(perm, sched is not None)))
@@ -366,15 +377,41 @@ def _hot_holders(store: NodeStore, step: int, manifest: dict) -> list[int]:
     return holders
 
 
+def _fused_stripe_words(code, words: int) -> int | None:
+    """Stripe width of a fused archive encode that would not fit the
+    device budget (``streaming.device_budget``); None where it fits."""
+    from repro.kernels.gf_encode import kernel
+    budget = streaming.device_budget()
+    if budget is None:
+        return None
+    return streaming.fused_stripe_words(
+        code.k, code.n, words, code.l, budget,
+        granule=gf.LANES[code.l] * kernel.DEFAULT_BLOCK)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_padded(rows: list[jax.Array], width: int) -> jax.Array:
+    """k device rows of at most ``width`` words -> one (k, width) array,
+    zero-padded on the device (a tail stripe is shorter)."""
+    x = jnp.stack(rows)
+    return jnp.pad(x, ((0, 0), (0, width - x.shape[-1])))
+
+
 def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
                             manifest: dict, code, perm: np.ndarray, nc: int,
                             sched: dict | None, plan: streaming.StreamPlan,
-                            use_devices: bool | None,
-                            reclaim_hot: bool) -> dict:
-    """The streamed migration: hot range-reads -> stripe encodes -> framed
-    coded writes, never holding the object (see ``archive_step``)."""
+                            on_chain: bool, reclaim_hot: bool) -> dict:
+    """The streamed migration: hot range-reads -> stripe encodes on the
+    device -> framed coded writes, never holding the object (see
+    ``archive_step``). Each stripe's k range reads go to the device as they
+    are and are stacked there; the stripe codes on the device chain
+    (``on_chain``) or in one fused kernel launch (``kernel_ops.encode_auto``)
+    through ``streaming.execute``, so the next stripe's reads and the last
+    one's writes overlap the device."""
+    from repro.kernels.gf_encode import ops as kernel_ops
     k, n, l = acfg.k, acfg.n, acfg.l
     wb = l // 8
+    dt = gf.WORD_DTYPE[l]
     if sched is not None:
         sched = {**sched, "num_chunks": int(nc)}
     holders = _hot_holders(store, step, manifest)
@@ -383,47 +420,52 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     # any coded write publishes (the writers abort on mismatch)
     orig_sha = [hashlib.sha256() for _ in range(k)]
 
-    def get_stripe(s: int) -> np.ndarray:
+    def get_stripe(s: int) -> list[np.ndarray]:
         lo, hi = plan.stripe_span(s)
         nb = (hi - lo) * wb
-        rows = np.zeros((k, plan.sc_words * wb), np.uint8)  # tail zero-padded
-        for j in range(k):
-            raw = store.get_range(holders[j], hot_rel[j], lo * wb, nb)
-            if len(raw) != nb:
-                raise ValueError(
-                    f"step {step}: hot block {j} short read (stripe {s}: "
-                    f"got {len(raw)} of {nb} bytes)")
-            orig_sha[j].update(raw)
-            rows[j, :nb] = np.frombuffer(raw, dtype=np.uint8)
-        return rows.view(gf.WORD_DTYPE[l])
+        rows = []
+        with span("stripe_read", stripe=s, bytes=k * nb):
+            for j in range(k):
+                raw = store.get_range(holders[j], hot_rel[j], lo * wb, nb)
+                if len(raw) != nb:
+                    raise ValueError(
+                        f"step {step}: hot block {j} short read (stripe "
+                        f"{s}: got {len(raw)} of {nb} bytes)")
+                with span("sha256", bytes=nb):
+                    orig_sha[j].update(raw)
+                rows.append(np.frombuffer(raw, dtype=dt))
+        return rows
 
     writers = [store.put_stream(int(perm[pos]), ARC.format(step=step, i=pos))
                for pos in range(n)]
     stripes: list[dict] = []
 
     def put_stripe(s: int, out_w: np.ndarray) -> None:
-        frame = _u8(out_w[:, :plan.stripe_words(s)])
-        recs = []
-        for pos in range(n):
-            blob = frame[pos].tobytes()
-            writers[pos].write(blob)
-            recs.append(digest(blob))
-        stripes.append({"words": int(plan.stripe_words(s)),
-                        "coded_digests": recs})
+        words = plan.stripe_words(s)
+        with span("stripe_write", stripe=s, bytes=n * words * wb):
+            recs = []
+            for pos in range(n):
+                blob = memoryview(out_w[pos, :words].view(np.uint8))
+                writers[pos].write(blob)
+                recs.append(digest(blob))
+        stripes.append({"words": int(words), "coded_digests": recs})
 
-    if use_devices is None:
-        use_devices = len(jax.devices()) >= n
+    if on_chain:
+        chain = chain_lib.encode_program(
+            code, plan.sc_words, nc,
+            order=_device_order(perm, sched is not None))
+
+        def program(rows):
+            return chain(_stack_padded(rows, plan.sc_words))
+        program.__name__ = "chain_encode"
+    else:
+        def program(rows):
+            return kernel_ops.encode_auto(
+                code.G, _stack_padded(rows, plan.sc_words), l)
+        program.__name__ = "encode_auto"
+
     try:
-        if use_devices and code.supports_chain_encode:
-            program = chain_lib.encode_program(
-                code, plan.sc_words, nc,
-                order=_device_order(perm, sched is not None))
-            streaming.execute(plan, program, get_stripe, put_stripe)
-        else:
-            # host oracle, stripe by stripe (positionwise: concatenation of
-            # stripe encodes == the monolithic encode, bit-exactly)
-            for s in range(plan.num_superchunks):
-                put_stripe(s, np.asarray(code.encode_np(get_stripe(s))))
+        streaming.execute(plan, program, get_stripe, put_stripe)
         for j in range(k):
             if orig_sha[j].hexdigest()[:16] != manifest["digests"][j]:
                 raise ValueError(
@@ -437,9 +479,10 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
         w.close()
 
     if reclaim_hot:
-        for node, held in enumerate(manifest["placement"]):
-            for j in held:
-                store.delete(node, HOT.format(step=step, j=j))
+        with span("reclaim"):
+            for node, held in enumerate(manifest["placement"]):
+                for j in held:
+                    store.delete(node, HOT.format(step=step, j=j))
     manifest = {
         **manifest, "tier": "archive", "family": acfg.family,
         "perm": [int(p) for p in perm],
@@ -911,8 +954,9 @@ def _repair_state(store: NodeStore, step: int,
         if not missing:
             return [], [], []
         alive = [p for p in range(manifest["n"]) if p not in dead]
-        with span("repair_plan"):
+        with span("repair_plan") as plan_span:
             helpers = code.repair_helpers(missing, alive)
+            plan_span.set_metadata(helpers=len(helpers))
         for h in helpers:
             if h not in raws:
                 raws[h] = store.get(perm[h], ARC.format(step=step, i=h))
@@ -1034,7 +1078,7 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
         else:
             # helpers is already the plan's decodable helper set, so the
             # plan over it returns the same set and an aligned R
-            with span("repair_plan"):
+            with span("repair_plan", helpers=len(helpers)):
                 _, R = fault_tolerance.repair_plan(code, missing, helpers)
             shards_dev = _to_device(rows)       # (B_obj, |helpers|, B)
             with span("kernel_launch", kernel="encode_packed"):

@@ -41,9 +41,17 @@ def spans(code, monkeypatch):
     path a code must not take raises."""
     seen = []
 
+    class Recorded(contextlib.nullcontext):
+        def __init__(self, args):
+            super().__init__(self)
+            self.args = args
+
+        def set_metadata(self, **more):
+            self.args.update(more)
+
     def record(name, **args):
         seen.append((name, args))
-        return contextlib.nullcontext()
+        return Recorded(args)
 
     def refuse(*args):
         raise AssertionError(f"{code.family} took the wrong path")
@@ -119,9 +127,11 @@ def test_host_copies_only_for_sub_packetized_codes(code, spans, tmp_path):
     names = [n for n, _ in spans]
     copies = ("host_copy" in names[:archived], "host_copy" in names[archived:])
     h2d = [a for n, a in spans if n == "h2d"]
+    plans = [a["helpers"] for n, a in spans if n == "repair_plan"]
     if code.positionwise:
         helpers = len(code.repair_helpers([0], list(range(1, N))))
         assert copies == (False, False)
+        assert plans == [helpers, helpers]
         assert [a["direct"] for a in h2d] == [K, helpers]
         assert [a["bytes"] for a in h2d] == [K * B * 2, helpers * B * 2]
     else:

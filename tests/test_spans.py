@@ -159,6 +159,17 @@ def test_h2d_sends_each_store_buffer_direct(traced):
         assert h2d["bytes"] == rows * BLOCK, verb
 
 
+def test_repair_plan_counts_its_helpers(traced):
+    """Both plan spans of a fused repair (the helper choice and the repair
+    matrix) carry ``helpers``, the rows the plan reads."""
+    events, _, _ = traced
+    helpers = ACFG.code().repair_helpers(
+        [LOST], [p for p in range(ACFG.n) if p != LOST])
+    plans = [s for n, *_, s in _inside(events, "repair")
+             if n == "repair_plan"]
+    assert [s["helpers"] for s in plans] == [len(helpers)] * 2
+
+
 def test_no_span_nests_in_its_own_name(traced):
     events, _, _ = traced
     ours = {n for names in EXPECTED.values() for n in names}
