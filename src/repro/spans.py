@@ -33,12 +33,18 @@ Every span the program emits: name, site, args.
   ``kernel``.
 * ``d2h``: ``np.asarray`` of the kernel's result, which waits for the
   device and then copies, per launch or per stripe (whose copy back was
-  started when it was dispatched); ``bytes``.
+  started when it was dispatched); ``bytes``, only the rows copied back.
+  A fused archive codes only the generator rows that are not unit rows,
+  so a systematic code's data rows never cross: ``_fused_encode``'s
+  ``passthrough`` counts the rows written from the verified hot buffers
+  instead (k per object for LRC and Xorbas, 0 for RapidRAID and MBR).
 * ``stripe_read``: one per stripe of a streamed archive
   (``_archive_step_streaming``): the stripe's hot range reads and the
   incremental hot digests (under ``sha256``); ``stripe``, ``bytes``.
 * ``stripe_write``: one per stripe of a streamed archive: the stripe's
-  framed coded writes and their per-stripe digests; ``stripe``, ``bytes``.
+  framed coded writes and their per-stripe digests; ``stripe``, ``bytes``
+  (all n rows), ``passthrough`` (the rows written from the stripe's own
+  hot range reads, as ``d2h``'s).
 * ``reclaim``: the hot-replica deletes of ``archive_step``.
 * ``repair_plan``: ``code.repair_helpers`` (``_repair_state``) and
   ``fault_tolerance.repair_plan`` (``repair_many``); ``helpers``, the

@@ -40,6 +40,7 @@ import dataclasses
 import functools
 import hashlib
 import json
+import types
 
 import jax
 import jax.numpy as jnp
@@ -125,11 +126,11 @@ def _u8(blocks_w: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(blocks_w).view(np.uint8)
 
 
-def _rows(blocks_u8: np.ndarray) -> list[memoryview]:
-    """Each row of a C-contiguous (rows, B) uint8 array (what ``_u8``
-    returns) as a memoryview, which ``store.put`` and ``digest`` take in
-    place, with no copy."""
-    return [memoryview(row) for row in blocks_u8]
+def _rows(rows) -> list[memoryview]:
+    """Each of a sequence of C-contiguous 1-D rows (any word dtype) as a
+    uint8 memoryview, which ``store.put`` and ``digest`` take in place,
+    with no copy."""
+    return [memoryview(_u8(row)) for row in rows]
 
 
 # ---------------------------------------------------------------------------
@@ -335,9 +336,9 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
             code, _words(hot_load(store, step, manifest), acfg.l),
             num_chunks=nc, order=_device_order(perm, sched is not None)))
     else:
-        coded_w = _fused_encode(
-            code, [_hot_rows(store, step, manifest, acfg.l)], acfg.l)[0]
-    coded_blobs = _rows(_u8(coded_w))
+        objs = [_hot_rows(store, step, manifest, acfg.l)]
+        coded_w = _codewords(code, objs, _fused_encode(code, objs, acfg.l))[0]
+    coded_blobs = _rows(coded_w)
 
     for pos in range(acfg.n):
         store.put(int(perm[pos]), ARC.format(step=step, i=pos),
@@ -407,7 +408,9 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     are and are stacked there; the stripe codes on the device chain
     (``on_chain``) or in one fused kernel launch (``kernel_ops.encode_auto``)
     through ``streaming.execute``, so the next stripe's reads and the last
-    one's writes overlap the device."""
+    one's writes overlap the device. The fused launch codes only the rows
+    that are not pass-through rows (``_passthrough``); those are written
+    from the stripe's own range reads."""
     from repro.kernels.gf_encode import ops as kernel_ops
     k, n, l = acfg.k, acfg.n, acfg.l
     wb = l // 8
@@ -419,6 +422,11 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     # hot digests accumulate as the stripes stream past; verified BEFORE
     # any coded write publishes (the writers abort on mismatch)
     orig_sha = [hashlib.sha256() for _ in range(k)]
+    # pass-through rows are written from the stripe's own range reads,
+    # held from get_stripe until put_stripe; the device codes the rest
+    passthrough = {} if on_chain else _passthrough(code)
+    coded = [i for i in range(n) if i not in passthrough]
+    held: dict[int, list[np.ndarray]] = {}
 
     def get_stripe(s: int) -> list[np.ndarray]:
         lo, hi = plan.stripe_span(s)
@@ -434,6 +442,7 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
                 with span("sha256", bytes=nb):
                     orig_sha[j].update(raw)
                 rows.append(np.frombuffer(raw, dtype=dt))
+        held[s] = rows
         return rows
 
     writers = [store.put_stream(int(perm[pos]), ARC.format(step=step, i=pos))
@@ -442,10 +451,14 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
 
     def put_stripe(s: int, out_w: np.ndarray) -> None:
         words = plan.stripe_words(s)
-        with span("stripe_write", stripe=s, bytes=n * words * wb):
+        hot, rest = held.pop(s), iter(out_w)
+        with span("stripe_write", stripe=s, bytes=n * words * wb,
+                  passthrough=len(passthrough)):
             recs = []
             for pos in range(n):
-                blob = memoryview(out_w[pos, :words].view(np.uint8))
+                row = (hot[passthrough[pos]] if pos in passthrough
+                       else next(rest))
+                blob = memoryview(row[:words].view(np.uint8))
                 writers[pos].write(blob)
                 recs.append(digest(blob))
         stripes.append({"words": int(words), "coded_digests": recs})
@@ -461,7 +474,7 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
     else:
         def program(rows):
             return kernel_ops.encode_auto(
-                code.G, _stack_padded(rows, plan.sc_words), l)
+                code.G[coded], _stack_padded(rows, plan.sc_words), l)
         program.__name__ = "encode_auto"
 
     try:
@@ -535,9 +548,28 @@ def _gather(objs) -> np.ndarray:
     return out
 
 
+@functools.lru_cache(maxsize=64)
+def _passthrough(code) -> types.MappingProxyType:
+    """{codeword row i: data row j} for every row of a positionwise code's
+    generator that is exactly the unit row e_j (a systematic code's data
+    rows); empty for a sub-packetized code, whose rows index message
+    symbols. A scaled unit row is not in it: it is coded like any other."""
+    if not code.positionwise:
+        return types.MappingProxyType({})
+    out = {}
+    for i, row in enumerate(np.asarray(code.G)):
+        (nz,) = np.nonzero(row)
+        if len(nz) == 1 and row[nz[0]] == 1:
+            out[i] = int(nz[0])
+    return types.MappingProxyType(out)
+
+
 def _fused_encode(code, objs_w, l: int) -> np.ndarray:
-    """O objects of k word rows -> (O, n, B) codeword words in ONE fused
-    kernel launch on the default device, the object axis on the kernel grid.
+    """O objects of k word rows -> (O, R, B) words of the R codeword rows
+    that are not pass-through rows (``_passthrough``), in row order, in ONE
+    fused kernel launch on the default device, the object axis on the
+    kernel grid. Only those rows are coded and copied back; ``_codewords``
+    puts the n rows together.
 
     ``objs_w`` is O sequences of k 1-D word rows. The message view is the
     identity for positionwise codes, whose rows go to the device as they
@@ -546,6 +578,7 @@ def _fused_encode(code, objs_w, l: int) -> np.ndarray:
     the same fused GF kernel.
     """
     from repro.kernels.gf_encode import ops as kernel_ops
+    O = len(objs_w)
     if code.positionwise:
         msgs_dev = _to_device(objs_w)
     else:
@@ -554,11 +587,27 @@ def _fused_encode(code, objs_w, l: int) -> np.ndarray:
             msgs = np.stack([np.asarray(code.to_message(o)) for o in objs_w])
         with span("h2d", bytes=msgs.nbytes, direct=0):
             msgs_dev = jnp.asarray(msgs)
+    passthrough = _passthrough(code)
+    coded = [i for i in range(len(code.G)) if i not in passthrough]
     with span("kernel_launch", kernel="encode_auto"):
-        rows_dev = kernel_ops.encode_auto(code.G, msgs_dev, l)
-    with span("d2h", bytes=rows_dev.nbytes):
+        rows_dev = kernel_ops.encode_auto(code.G[coded], msgs_dev, l)
+    with span("d2h", bytes=rows_dev.nbytes,
+              passthrough=O * len(passthrough)):
         rows = np.asarray(rows_dev)
-    return rows.reshape(len(objs_w), code.n, -1)
+    return rows.reshape(O, code.n - len(passthrough), -1)
+
+
+def _codewords(code, objs_w, coded_w) -> list[list[np.ndarray]]:
+    """Per object, its n codeword rows as 1-D word rows, stacked nowhere:
+    a pass-through row is the verified hot row it equals (``objs_w``), any
+    other the next row of ``_fused_encode``'s result ``coded_w``."""
+    passthrough = _passthrough(code)
+    out = []
+    for obj, coded in zip(objs_w, coded_w):
+        rest = iter(coded)
+        out.append([obj[passthrough[i]] if i in passthrough else next(rest)
+                    for i in range(code.n)])
+    return out
 
 
 def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
@@ -568,8 +617,8 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
                    ) -> dict[int, dict]:
     """Encode one rectangular (same block length, same placement) batch of
     hot steps and place/manifest the coded blocks."""
-    # blocks are loaded one group at a time (and released after the
-    # group's encode) so peak host memory is one group, not the batch
+    # blocks are loaded one group at a time (and released once the group
+    # is written) so peak host memory is one group, not the batch
     rows = [_hot_rows(store, s, manifests[s], acfg.l) for s in grp]
     B = manifests[grp[0]]["block_bytes"] // (acfg.l // 8)
     nc = num_chunks
@@ -582,11 +631,10 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
             code, _gather(rows), num_chunks=nc, stagger=stagger,
             order=_device_order(perm, sched is not None)))
     else:
-        coded_w = _fused_encode(code, rows, acfg.l)
-    del rows
+        coded_w = _codewords(code, rows, _fused_encode(code, rows, acfg.l))
     out: dict[int, dict] = {}
     for b, step in enumerate(grp):
-        coded_blobs = _rows(_u8(coded_w[b]))
+        coded_blobs = _rows(coded_w[b])
         for pos in range(acfg.n):
             store.put(int(perm[pos]), ARC.format(step=step, i=pos),
                       coded_blobs[pos])

@@ -16,7 +16,7 @@ from repro.core import codes, gf
 from repro.storage import archive as arc
 from repro.storage import object_store as obj
 
-FAMILIES = ("rapidraid", "lrc", "mbr")
+FAMILIES = ("rapidraid", "lrc", "xorbas", "mbr")
 N, K, L = 8, 4, 16
 B = 512                                     # words per block
 
@@ -74,7 +74,8 @@ def _assert_files(store, code, step, data):
 def test_fused_archive_falls_back_past_a_corrupt_replica(code, spans, tmp_path):
     """A hot replica whose bytes fail their digest is skipped for the other
     holder before anything goes to the device; the coded files match the
-    oracle."""
+    oracle, a systematic code's data row 0 (written from the hot buffer)
+    among them."""
     store = obj.NodeStore(str(tmp_path), N)
     data = _payload(1)
     manifest = arc.hot_save(store, 1, data.view(np.uint8), _acfg(code))

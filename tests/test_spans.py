@@ -21,6 +21,8 @@ from repro.storage import object_store as obj
 from repro.storage.client import StorageClient
 
 ACFG = arc.ArchiveConfig(n=16, k=11, l=16)
+#: Azure's LRC(12,2,2): rows 0..11 of its generator are unit rows
+LRC = arc.ArchiveConfig(n=16, k=12, l=16, family="lrc")
 BLOCK = 32 << 10
 LOST = 5
 RANGE = (BLOCK - 4096, 8192)        # crosses from block 0 into block 1
@@ -39,13 +41,13 @@ EXPECTED = {
 }
 
 
-def _session(root: str, label=None) -> obj.NodeStore:
+def _session(root: str, label=None, acfg=ACFG) -> obj.NodeStore:
     """Archive, lose a row, repair, read a range; ``label`` marks each verb
     (a ``jax.profiler.TraceAnnotation`` of the verb's name) when given."""
-    store = obj.NodeStore(root, ACFG.n)
-    client = StorageClient(store, ACFG)
+    store = obj.NodeStore(root, acfg.n)
+    client = StorageClient(store, acfg)
     rng = np.random.default_rng(13)
-    blocks = rng.integers(0, 256, size=(ACFG.k, BLOCK), dtype=np.uint8)
+    blocks = rng.integers(0, 256, size=(acfg.k, BLOCK), dtype=np.uint8)
     client.put_hot(1, blocks)
     mark = label or (lambda name: contextlib.nullcontext())
     with mark("archive"):
@@ -71,19 +73,18 @@ def _files(root: str) -> dict[str, bytes]:
     return out
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def _traced_session(base, acfg):
     """(host events as (name, start, end, stats), plain files, traced files);
     the plain run goes first, so the traced one holds no compile."""
-    base = tmp_path_factory.mktemp("spans")
-    plain = _files(_session(str(base / "plain")).root)
+    plain = _files(_session(str(base / "plain"), acfg=acfg).root)
     prof = str(base / "profile")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
     jax.profiler.start_trace(prof, profiler_options=opts)
     try:
-        store = _session(str(base / "traced"), jax.profiler.TraceAnnotation)
+        store = _session(str(base / "traced"), jax.profiler.TraceAnnotation,
+                         acfg)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
@@ -96,6 +97,16 @@ def traced(tmp_path_factory):
             events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                         dict(e.stats)) for e in line.events]
     return events, plain, _files(store.root)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return _traced_session(tmp_path_factory.mktemp("spans"), ACFG)
+
+
+@pytest.fixture(scope="module")
+def traced_lrc(tmp_path_factory):
+    return _traced_session(tmp_path_factory.mktemp("spans_lrc"), LRC)
 
 
 def _inside(events, verb):
@@ -183,3 +194,17 @@ def test_stored_bytes_unchanged_by_the_profiler(traced):
     _, plain, traced_files = traced
     assert plain.keys() == traced_files.keys()
     assert all(plain[p] == traced_files[p] for p in plain)
+
+
+@pytest.mark.parametrize("session, acfg, through", [
+    ("traced", ACFG, 0), ("traced_lrc", LRC, LRC.k)], ids=["rapidraid", "lrc"])
+def test_d2h_copies_back_only_rows_that_are_not_data(request, session, acfg,
+                                                     through):
+    """A systematic code's data rows are written from the hot buffers
+    (``passthrough``), so ``d2h`` copies back only the other rows; the
+    profiler leaves the stored bytes as they are."""
+    events, plain, traced_files = request.getfixturevalue(session)
+    (d2h,) = [s for n, *_, s in _inside(events, "archive") if n == "d2h"]
+    assert d2h["passthrough"] == through
+    assert d2h["bytes"] == (acfg.n - through) * BLOCK
+    assert plain == traced_files

@@ -116,15 +116,19 @@ def _repair_R():
     return R
 
 
-@pytest.mark.parametrize("entry", ["archive", "archive_many", "repair"])
+@pytest.mark.parametrize("entry", ["archive", "archive_many", "repair",
+                                   "archive_parity"])
 def test_fused_encode_program(one_chip, entry):
     """The jitted ``encode_packed`` program behind ``archive_step`` (one
     object), ``archive_many`` (two in one launch) and ``repair_many`` (the
-    five-row repair matrix) at the paper's object size."""
-    M = _repair_R() if entry == "repair" else _code().G
+    five-row repair matrix) at the paper's object size, and an LRC(12,2,2)
+    archive, which codes only the 4 parity rows of its generator."""
+    M = {"repair": _repair_R(),
+         "archive_parity": codes.make("lrc", 16, 12, l=16).G[12:]
+         }.get(entry, _code().G)
     objs = 2 if entry == "archive_many" else 1
-    x = jax.ShapeDtypeStruct((objs, 11, LANES_U32), jnp.uint32,
-                             sharding=one_chip)
+    x = jax.ShapeDtypeStruct((objs, np.asarray(M).shape[1], LANES_U32),
+                             jnp.uint32, sharding=one_chip)
     block = ops.pick_block(LANES_U32)
     _compile(ops._encode_packed_jit, x, _key(M), 16, block, False)
 
