@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import gf, jitcache, rapidraid, streaming
+from repro.core import jitcache, rapidraid, streaming
 from repro.storage import archive as arc
 from repro.storage import chain as chain_lib
 from repro.storage import object_store as obj
@@ -204,14 +204,6 @@ def _build_restore(code, ids: tuple, layout: StateLayout, order,
     return program
 
 
-def _chunk_count(Bw: int, l: int, num_chunks: int) -> int:
-    """Largest feasible chunk count (same reduction as ``archive_step``)."""
-    nc = num_chunks
-    while nc > 1 and Bw % (gf.LANES[l] * nc):
-        nc //= 2
-    return nc
-
-
 def _mesh_order(mesh, n: int):
     from repro.train import sharding
     return None if mesh is None else sharding.chain_order(mesh, n)
@@ -265,12 +257,12 @@ def save_state(store, step: int, state, acfg: arc.ArchiveConfig,
             store, step, acfg, blocks, len(blob),
             superchunk_bytes=sc_words * (acfg.l // 8),
             state_key=layout.key[0], use_devices=use_devices)
-    nc = _chunk_count(B * 8 // acfg.l, acfg.l, num_chunks or acfg.num_chunks)
+    nc = streaming.chunk_count(B * 8 // acfg.l, acfg.l,
+                               num_chunks or acfg.num_chunks)
     order = _mesh_order(mesh, acfg.n)
-    if use_devices is None:
-        use_devices = (order is not None if mesh is not None
-                       else len(jax.devices()) >= acfg.n)
-    use_chain = (use_devices and code.supports_chain_encode
+    if use_devices is None and mesh is not None:
+        use_devices = order is not None
+    use_chain = (arc._use_chain(code, use_devices, acfg.n)
                  and len(jax.devices()) >= acfg.n)
     okey = tuple(order) if order is not None else None
     fn = jitcache.get(
@@ -343,13 +335,13 @@ def restore_state(store, step: int, like, acfg: arc.ArchiveConfig,
         shards_w = arc._words(
             np.stack([np.frombuffer(raws[h], dtype=np.uint8)
                       for h in helpers]), manifest["l"])
-        nc = _chunk_count(shards_w.shape[1], manifest["l"],
-                          num_chunks or acfg.num_chunks)
+        nc = streaming.chunk_count(shards_w.shape[1], manifest["l"],
+                                   num_chunks or acfg.num_chunks)
         order = _mesh_order(mesh, len(helpers))
-        if use_devices is None:
-            use_devices = (order is not None if mesh is not None
-                           else len(jax.devices()) >= len(helpers))
-        use_chain = (use_devices and code.positionwise
+        if use_devices is None and mesh is not None:
+            use_devices = order is not None
+        use_chain = (arc._use_chain(code, use_devices, len(helpers),
+                                    repair=True)
                      and len(jax.devices()) >= len(helpers))
         okey = tuple(order) if order is not None else None
         fn = jitcache.get(

@@ -108,6 +108,18 @@ def plan_stream(total_words: int, superchunk_words: int | None, *,
     return StreamPlan(total_words, sc, num, tail)
 
 
+def chunk_count(words: int, l: int, num_chunks: int) -> int:
+    """Largest feasible pipeline chunk count, halving down from
+    ``num_chunks``: every chunk of ``words`` must be whole uint32 lanes
+    (the device chain's granularity; the host oracle only needs
+    ``nc | words``, which the stricter condition implies). A streaming
+    plan's stripe width is already a multiple of the granule."""
+    nc = num_chunks
+    while nc > 1 and words % (gf.LANES[l] * nc):
+        nc //= 2
+    return nc
+
+
 def estimate_stripe_bytes(code, sc_words: int, *, rows_in: int | None = None,
                           rows_out: int | None = None) -> int:
     """Modeled peak live device bytes for one stripe of the chain encode.

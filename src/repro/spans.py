@@ -13,7 +13,7 @@ Every span the program emits: name, site, args.
 * ``hot_load``: ``archive._hot_load_ex`` (store calls, digests, row
   copies) and ``archive._hot_rows`` (store calls, digests); ``bytes``.
 * ``sha256``: ``object_store.digest``, which every whole digest goes
-  through, and the streamed archive's incremental hot digests; ``bytes``.
+  through, and the stripe body's incremental block digests; ``bytes``.
 * ``host_copy``: host-side copies of payload, where the payload must sit
   in one host array: hot rows into the object (``_hot_load_ex``), rows
   gathered for a sub-packetized code or a device mesh (``_gather``), the
@@ -38,14 +38,18 @@ Every span the program emits: name, site, args.
   so a systematic code's data rows never cross: ``_fused_encode``'s
   ``passthrough`` counts the rows written from the verified hot buffers
   instead (k per object for LRC and Xorbas, 0 for RapidRAID and MBR).
-* ``stripe_read``: one per stripe of a streamed archive
-  (``_archive_step_streaming``): the stripe's hot range reads and the
-  incremental hot digests (under ``sha256``); ``stripe``, ``bytes``.
-* ``stripe_write``: one per stripe of a streamed archive: the stripe's
-  framed coded writes and their per-stripe digests; ``stripe``, ``bytes``
-  (all n rows), ``passthrough`` (the rows written from the stripe's own
-  hot range reads, as ``d2h``'s).
-* ``reclaim``: the hot-replica deletes of ``archive_step``.
+* ``stripe_read``: one per stripe in the one stripe body,
+  ``archive._archive_step_streaming``, which codes a streamed
+  ``archive_step`` and ``publish_streaming_archive``: the stripe's reads
+  (hot range reads, or slices of the in-memory blocks) and their
+  incremental digests (under ``sha256``); ``stripe``, ``bytes``.
+* ``stripe_write``: one per stripe in the same body: the stripe's framed
+  coded writes and their per-stripe digests; ``stripe``, ``bytes`` (all n
+  rows), ``passthrough`` (the rows written from the stripe's own reads,
+  as ``d2h``'s).
+* ``reclaim``: the hot-replica deletes in ``archive._publish``, which
+  every archive of a hot step ends in (``archive_step``,
+  ``archive_many``, whole or in stripes).
 * ``repair_plan``: ``code.repair_helpers`` (``_repair_state``) and
   ``fault_tolerance.repair_plan`` (``repair_many``); ``helpers``, the
   rows the plan reads.

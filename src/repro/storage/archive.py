@@ -8,15 +8,13 @@ This is the paper's lifecycle applied to training checkpoints:
    layout pipelined insertion produces and the precondition for chain
    encoding (paper §V).
 2. **archive_step** — the migration: the n nodes run the pipelined encode
-   (each node combines what it stores with the running combination from its
-   predecessor — ``repro.storage.chain`` over a device chain, or one fused
-   kernel launch on the default device when there are fewer devices than
-   nodes), each node keeps its coded block c_i, replicas are dropped.
-   Storage falls from 2x to n/k (1.45x for (16,11)).
-   **archive_many** batches the migration: B pending steps are encoded
-   concurrently through the staggered multi-chain (``repro.storage.multi``)
-   or, off-device, one fused batched pallas launch — the paper's
-   multi-object archival (§VI).
+   (``repro.storage.chain`` over a device chain, or one fused kernel launch
+   on the default device when there are fewer devices than nodes), each
+   node keeps its coded block c_i, replicas are dropped: storage falls from
+   2x to n/k. **archive_many** encodes B steps concurrently (the paper's
+   multi-object archival, §VI). Every write path codes through
+   ``_archive_group`` or the stripe body ``_archive_step_streaming``, and
+   publishes through ``_publish``.
 3. **restore** — any k live coded blocks reconstruct the object (GF
    Gaussian elimination on the host builds the decode matrix; the matmul
    runs through the same GF path). ``read_range`` serves byte ranges
@@ -65,7 +63,6 @@ class ArchiveConfig:
     l: int = 16               # GF(2^16): random coefficients suffice (§V-A)
     seed: int = 0
     num_chunks: int = 8       # pipeline chunks per block
-    baseline: str = "rapidraid"  # or "classical" (CEC; for benchmarks)
     family: str = "rapidraid"    # registered code family (repro.core.codes)
 
     def code(self) -> codes.ErasureCode:
@@ -141,22 +138,28 @@ def _rows(rows) -> list[memoryview]:
 def hot_save(store: NodeStore, step: int, blocks: np.ndarray,
              acfg: ArchiveConfig) -> dict:
     """blocks (k, B) uint8 -> two overlapped replicas over n nodes."""
-    place = rapidraid.placement(acfg.n, acfg.k)
     k, B = blocks.shape
     assert k == acfg.k
     # serialize each block ONCE: every replica put and the digest reuse it
     blobs = [blocks[j].tobytes() for j in range(k)]
-    for node, held in enumerate(place):
+    for node, held in enumerate(rapidraid.placement(acfg.n, acfg.k)):
         for j in held:
             store.put(node, HOT.format(step=step, j=j), blobs[j])
-    manifest = {
-        "step": step, "tier": "hot", "n": acfg.n, "k": acfg.k, "l": acfg.l,
-        "seed": acfg.seed, "family": acfg.family, "block_bytes": int(B),
-        "digests": [digest(b) for b in blobs],
-        "placement": [list(h) for h in place],
-    }
+    manifest = _base_manifest(step, acfg, B, [digest(b) for b in blobs])
     _put_manifest(store, step, manifest)
     return manifest
+
+
+def _base_manifest(step: int, acfg: ArchiveConfig, block_bytes: int,
+                   digests: list[str] | None, tier: str = "hot") -> dict:
+    """The fields every manifest starts with; a step written straight to
+    the coded tier keeps the nominal hot placement (one schema)."""
+    return {
+        "step": step, "tier": tier, "n": acfg.n, "k": acfg.k, "l": acfg.l,
+        "seed": acfg.seed, "family": acfg.family,
+        "block_bytes": int(block_bytes), "digests": digests,
+        "placement": [list(h) for h in rapidraid.placement(acfg.n, acfg.k)],
+    }
 
 
 def hot_load(store: NodeStore, step: int, manifest: dict) -> np.ndarray:
@@ -253,6 +256,58 @@ def _device_order(perm: np.ndarray, scheduled: bool) -> list[int] | None:
     return None
 
 
+def _use_chain(code, use_devices: bool | None, rows: int, *,
+               repair: bool = False) -> bool:
+    """Code on a chain of ``rows`` devices, not in one fused launch:
+    ``use_devices`` (None: at least ``rows`` local devices) and a family
+    with the chain (``repair``: any positionwise code's helper chain)."""
+    if use_devices is None:
+        use_devices = len(jax.devices()) >= rows
+    return bool(use_devices) and (code.positionwise if repair
+                                  else code.supports_chain_encode)
+
+
+def _drop_hot(store: NodeStore, step: int, manifest: dict) -> None:
+    """Delete every hot replica the manifest's placement holds."""
+    for node, held in enumerate(manifest["placement"]):
+        for j in held:
+            store.delete(node, HOT.format(step=step, j=j))
+
+
+def _put_coded(store: NodeStore, step: int, perm, rows) -> list[memoryview]:
+    """Write codeword row p on node ``perm[p]``; -> the rows as written."""
+    blobs = _rows(rows)
+    for pos, blob in enumerate(blobs):
+        store.put(int(perm[pos]), ARC.format(step=step, i=pos), blob)
+    return blobs
+
+
+def _publish(store: NodeStore, step: int, base: dict, acfg: ArchiveConfig,
+             perm, coded_digests, *, reclaim_hot: bool = True,
+             sched: dict | None = None, **extra) -> dict:
+    """Publish a step whose n coded rows are stored. A step coded from the
+    hot tier first drops its replicas (2x -> n/k) or, with ``reclaim_hot``
+    false, records ``hot_retained``; ``coded_digests`` is read after that.
+    ``extra`` entries that are not None join the manifest."""
+    hot = base["tier"] == "hot"
+    if hot and reclaim_hot:
+        with span("reclaim"):
+            _drop_hot(store, step, base)
+    manifest = {
+        **base, "tier": "archive", "family": acfg.family,
+        "perm": [int(p) for p in perm],
+        "coded_digests": list(coded_digests),
+        "orig_digests": base["digests"],
+        **{key: v for key, v in extra.items() if v is not None},
+    }
+    if hot and not reclaim_hot:
+        manifest["hot_retained"] = True
+    if sched is not None:
+        manifest["sched"] = sched
+    _put_manifest(store, step, manifest)
+    return manifest
+
+
 def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
                  node_speeds: np.ndarray | None = None,
                  use_devices: bool | None = None,
@@ -265,25 +320,17 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
     the topology's makespan model and recorded in the manifest
     (``perm`` / ``sched``), so repair and decode reuse the placement.
 
-    ``superchunk_bytes`` streams the migration: the object archives as
-    independent super-chunk stripes through the streaming executor
-    (``repro.core.streaming``) — each stripe's hot slices are range-read
-    off the replicas, encoded through ONE cached pipeline program, and
-    framed into atomic ``put_stream`` writers, so neither peak device nor
-    peak host bytes ever hold the object. Positionwise codes write coded
-    blocks BYTE-IDENTICAL to the monolithic path (same digests, every
-    existing reader works unchanged); the manifest additionally records
-    the stripe geometry + per-stripe digests (``streaming``) so restore
-    and scrub can verify stripe-by-stripe. Hot digests are checked
-    incrementally as stripes are read, and a mismatch aborts the coded
-    writes BEFORE anything is published. Sub-packetized families cannot
-    stream (raises ValueError).
-
-    Without ``superchunk_bytes``, a positionwise code coded off the chain
-    streams by itself when one fused launch would hold more device memory
-    than ``streaming.device_budget`` allows
-    (``streaming.fused_encode_bytes``): it then codes in the fewest equal
-    stripes that fit (``streaming.fused_stripe_words``).
+    ``superchunk_bytes`` streams the migration through the stripe body
+    (``_archive_step_streaming``): neither peak device nor peak host bytes
+    hold the object, positionwise codes write coded blocks BYTE-IDENTICAL
+    to the monolithic path, and the manifest adds the stripe geometry and
+    per-stripe digests (``streaming``). A hot digest mismatch aborts the
+    coded writes before anything is published. Sub-packetized families
+    cannot stream (raises ValueError). Without ``superchunk_bytes``, a
+    positionwise code off the chain streams by itself when one fused launch
+    would hold more than ``streaming.device_budget`` allows, in the fewest
+    equal stripes that fit (``streaming.fused_stripe_words``). Otherwise
+    the step codes as ``archive_many``'s group of one (``_archive_group``).
 
     ``reclaim_hot=False`` defers the replica deletion: the step is coded
     and readable from the archive tier, but the hot replicas stay on disk
@@ -302,9 +349,7 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
 
     wb = acfg.l // 8
     words = manifest["block_bytes"] // wb
-    if use_devices is None:
-        use_devices = len(jax.devices()) >= acfg.n
-    on_chain = use_devices and code.supports_chain_encode
+    on_chain = _use_chain(code, use_devices, acfg.n)
     sc_words = None
     if superchunk_bytes is not None:
         sc_words = max(1, superchunk_bytes // wb)
@@ -314,58 +359,17 @@ def archive_step(store: NodeStore, step: int, acfg: ArchiveConfig,
         plan = streaming.plan_stream(words, sc_words, l=acfg.l,
                                      num_chunks=nc)
         if plan.streaming:
-            if not code.positionwise:
-                raise ValueError(
-                    f"archive_step: {code.family} is sub-packetized — "
-                    f"stripe concatenation is not a codeword, so it cannot "
-                    f"stream (archive without superchunk_bytes)")
             return _archive_step_streaming(
                 store, step, acfg, manifest, code, perm, nc, sched, plan,
-                on_chain, reclaim_hot)
+                on_chain, reclaim_hot, _hot_reader(store, step, manifest))
         # plan degenerated to one stripe: the monolithic path IS the stream
-
-    # largest feasible chunk count: every chunk must be whole uint32 lanes
-    # (the device chain's granularity; the host oracle only needs nc | B,
-    # which the stricter condition implies)
-    while nc > 1 and words % (gf.LANES[acfg.l] * nc):
-        nc //= 2
-    if sched is not None:
-        sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
-    if on_chain:
-        coded_w = np.asarray(chain_lib.pipelined_encode(
-            code, _words(hot_load(store, step, manifest), acfg.l),
-            num_chunks=nc, order=_device_order(perm, sched is not None)))
-    else:
-        objs = [_hot_rows(store, step, manifest, acfg.l)]
-        coded_w = _codewords(code, objs, _fused_encode(code, objs, acfg.l))[0]
-    coded_blobs = _rows(coded_w)
-
-    for pos in range(acfg.n):
-        store.put(int(perm[pos]), ARC.format(step=step, i=pos),
-                  coded_blobs[pos])
-    if reclaim_hot:
-        # drop the hot replicas (the actual capacity saving: 2x -> n/k)
-        with span("reclaim"):
-            for node, held in enumerate(manifest["placement"]):
-                for j in held:
-                    store.delete(node, HOT.format(step=step, j=j))
-
-    manifest = {
-        **manifest, "tier": "archive", "family": acfg.family,
-        "perm": [int(p) for p in perm],
-        "coded_digests": [digest(b) for b in coded_blobs],
-        "orig_digests": manifest["digests"],
-    }
-    if not reclaim_hot:
-        manifest["hot_retained"] = True
-    if sched is not None:
-        manifest["sched"] = sched
-    _put_manifest(store, step, manifest)
-    return manifest
+    return _archive_group(store, [step], acfg, code, perm, nc, 1, on_chain,
+                          {step: manifest}, sched, reclaim_hot)[step]
 
 
-def _hot_holders(store: NodeStore, step: int, manifest: dict) -> list[int]:
-    """One replica-holding node per hot block (existence probe only)."""
+def _hot_reader(store: NodeStore, step: int, manifest: dict):
+    """``read(j, offset, nbytes)`` of hot block j, from one replica
+    holder per block (existence probe only)."""
     holders = []
     for j in range(manifest["k"]):
         rel = HOT.format(step=step, j=j)
@@ -375,7 +379,8 @@ def _hot_holders(store: NodeStore, step: int, manifest: dict) -> list[int]:
             raise FileNotFoundError(
                 f"hot block {j} of step {step} lost on all replicas")
         holders.append(cands[0])
-    return holders
+    return lambda j, offset, nbytes: store.get_range(
+        holders[j], HOT.format(step=step, j=j), offset, nbytes)
 
 
 def _fused_stripe_words(code, words: int) -> int | None:
@@ -399,31 +404,35 @@ def _stack_padded(rows: list[jax.Array], width: int) -> jax.Array:
 
 
 def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
-                            manifest: dict, code, perm: np.ndarray, nc: int,
+                            base: dict, code, perm, nc: int,
                             sched: dict | None, plan: streaming.StreamPlan,
-                            on_chain: bool, reclaim_hot: bool) -> dict:
-    """The streamed migration: hot range-reads -> stripe encodes on the
-    device -> framed coded writes, never holding the object (see
-    ``archive_step``). Each stripe's k range reads go to the device as they
-    are and are stacked there; the stripe codes on the device chain
-    (``on_chain``) or in one fused kernel launch (``kernel_ops.encode_auto``)
-    through ``streaming.execute``, so the next stripe's reads and the last
-    one's writes overlap the device. The fused launch codes only the rows
-    that are not pass-through rows (``_passthrough``); those are written
-    from the stripe's own range reads."""
+                            on_chain: bool, reclaim_hot: bool,
+                            read, **extra) -> dict:
+    """The one stripe body: stripe reads -> stripe encodes on the device ->
+    framed coded writes, never holding the object, then ``_publish``.
+
+    ``read(j, offset, nbytes)`` gives those bytes of block j: hot range
+    reads (``_hot_reader``) or slices of in-memory blocks. Their digests
+    accumulate as the stripes pass and must equal ``base["digests"]``
+    (None: taken from the stream) before anything publishes. Each stripe's
+    k rows are stacked on the device and coded on the chain or in one
+    fused launch of the rows that are not pass-through rows, which are
+    written from the stripe's own reads; ``streaming.execute`` overlaps
+    the next stripe's reads and the last one's writes with the device."""
     from repro.kernels.gf_encode import ops as kernel_ops
+    if not code.positionwise:
+        raise ValueError(
+            f"{code.family} is sub-packetized — stripe concatenation is not "
+            f"a codeword, so it cannot stream (archive it whole)")
     k, n, l = acfg.k, acfg.n, acfg.l
     wb = l // 8
     dt = gf.WORD_DTYPE[l]
+    nc = streaming.chunk_count(plan.sc_words, l, nc)
     if sched is not None:
-        sched = {**sched, "num_chunks": int(nc)}
-    holders = _hot_holders(store, step, manifest)
-    hot_rel = [HOT.format(step=step, j=j) for j in range(k)]
-    # hot digests accumulate as the stripes stream past; verified BEFORE
-    # any coded write publishes (the writers abort on mismatch)
-    orig_sha = [hashlib.sha256() for _ in range(k)]
-    # pass-through rows are written from the stripe's own range reads,
-    # held from get_stripe until put_stripe; the device codes the rest
+        sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
+    sha = [hashlib.sha256() for _ in range(k)]
+    # pass-through rows are written from the stripe's own reads, held
+    # from get_stripe until put_stripe; the device codes the rest
     passthrough = {} if on_chain else _passthrough(code)
     coded = [i for i in range(n) if i not in passthrough]
     held: dict[int, list[np.ndarray]] = {}
@@ -434,13 +443,13 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
         rows = []
         with span("stripe_read", stripe=s, bytes=k * nb):
             for j in range(k):
-                raw = store.get_range(holders[j], hot_rel[j], lo * wb, nb)
+                raw = read(j, lo * wb, nb)
                 if len(raw) != nb:
                     raise ValueError(
-                        f"step {step}: hot block {j} short read (stripe "
+                        f"step {step}: block {j} short read (stripe "
                         f"{s}: got {len(raw)} of {nb} bytes)")
                 with span("sha256", bytes=nb):
-                    orig_sha[j].update(raw)
+                    sha[j].update(raw)
                 rows.append(np.frombuffer(raw, dtype=dt))
         held[s] = rows
         return rows
@@ -451,36 +460,32 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
 
     def put_stripe(s: int, out_w: np.ndarray) -> None:
         words = plan.stripe_words(s)
-        hot, rest = held.pop(s), iter(out_w)
+        (rows,) = _codewords(passthrough, [held.pop(s)], [out_w])
         with span("stripe_write", stripe=s, bytes=n * words * wb,
                   passthrough=len(passthrough)):
             recs = []
-            for pos in range(n):
-                row = (hot[passthrough[pos]] if pos in passthrough
-                       else next(rest))
+            for pos, row in enumerate(rows):
                 blob = memoryview(row[:words].view(np.uint8))
                 writers[pos].write(blob)
                 recs.append(digest(blob))
         stripes.append({"words": int(words), "coded_digests": recs})
 
-    if on_chain:
-        chain = chain_lib.encode_program(
-            code, plan.sc_words, nc,
-            order=_device_order(perm, sched is not None))
+    encode = (chain_lib.encode_program(
+        code, plan.sc_words, nc, order=_device_order(perm, sched is not None))
+        if on_chain else functools.partial(kernel_ops.encode_auto,
+                                           code.G[coded], l=l))
 
-        def program(rows):
-            return chain(_stack_padded(rows, plan.sc_words))
-        program.__name__ = "chain_encode"
-    else:
-        def program(rows):
-            return kernel_ops.encode_auto(
-                code.G[coded], _stack_padded(rows, plan.sc_words), l)
-        program.__name__ = "encode_auto"
+    def program(rows):
+        return encode(_stack_padded(rows, plan.sc_words))
+    program.__name__ = "chain_encode" if on_chain else "encode_auto"
 
     try:
         streaming.execute(plan, program, get_stripe, put_stripe)
+        hashed = [h.hexdigest()[:16] for h in sha]
+        if base["digests"] is None:     # in-memory blocks: their own record
+            base = {**base, "digests": hashed}
         for j in range(k):
-            if orig_sha[j].hexdigest()[:16] != manifest["digests"][j]:
+            if hashed[j] != base["digests"][j]:
                 raise ValueError(
                     f"step {step}: hot block {j} does not match its manifest "
                     f"digest — streamed archive aborted, nothing published")
@@ -490,32 +495,14 @@ def _archive_step_streaming(store: NodeStore, step: int, acfg: ArchiveConfig,
         raise
     for w in writers:
         w.close()
-
-    if reclaim_hot:
-        with span("reclaim"):
-            for node, held in enumerate(manifest["placement"]):
-                for j in held:
-                    store.delete(node, HOT.format(step=step, j=j))
-    manifest = {
-        **manifest, "tier": "archive", "family": acfg.family,
-        "perm": [int(p) for p in perm],
-        # incremental frame hashes == whole-file digests, identical to the
-        # monolithic path's (the files are byte-identical)
-        "coded_digests": [w.digest() for w in writers],
-        "orig_digests": manifest["digests"],
-        "streaming": {
-            "num_superchunks": int(plan.num_superchunks),
-            "superchunk_bytes": int(plan.sc_words * wb),
-            "num_chunks": int(nc),
-            "stripes": stripes,
-        },
-    }
-    if not reclaim_hot:
-        manifest["hot_retained"] = True
-    if sched is not None:
-        manifest["sched"] = sched
-    _put_manifest(store, step, manifest)
-    return manifest
+    # incremental frame hashes == whole-file digests, identical to the
+    # monolithic path's (the files are byte-identical)
+    return _publish(
+        store, step, base, acfg, perm, [w.digest() for w in writers],
+        reclaim_hot=reclaim_hot, sched=sched, **extra,
+        streaming={"num_superchunks": int(plan.num_superchunks),
+                   "superchunk_bytes": int(plan.sc_words * wb),
+                   "num_chunks": int(nc), "stripes": stripes})
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -565,17 +552,13 @@ def _passthrough(code) -> types.MappingProxyType:
 
 
 def _fused_encode(code, objs_w, l: int) -> np.ndarray:
-    """O objects of k word rows -> (O, R, B) words of the R codeword rows
-    that are not pass-through rows (``_passthrough``), in row order, in ONE
-    fused kernel launch on the default device, the object axis on the
-    kernel grid. Only those rows are coded and copied back; ``_codewords``
-    puts the n rows together.
-
-    ``objs_w`` is O sequences of k 1-D word rows. The message view is the
-    identity for positionwise codes, whose rows go to the device as they
-    are (``_to_device``), and the sub-packetized (M_sub, W) layout for
-    regenerating codes, built on the host, so EVERY family encodes through
-    the same fused GF kernel.
+    """O objects (sequences of k 1-D word rows) -> (O, R, B) words of the
+    R codeword rows that are not pass-through rows (``_passthrough``), in
+    row order, in ONE fused kernel launch, the object axis on the kernel
+    grid; ``_codewords`` puts the n rows together. Positionwise rows go to
+    the device as they are (``_to_device``); a regenerating code's
+    sub-packetized message is built on the host, so EVERY family encodes
+    through the same fused GF kernel.
     """
     from repro.kernels.gf_encode import ops as kernel_ops
     O = len(objs_w)
@@ -597,65 +580,52 @@ def _fused_encode(code, objs_w, l: int) -> np.ndarray:
     return rows.reshape(O, code.n - len(passthrough), -1)
 
 
-def _codewords(code, objs_w, coded_w) -> list[list[np.ndarray]]:
+def _codewords(passthrough, objs_w, coded_w) -> list[list[np.ndarray]]:
     """Per object, its n codeword rows as 1-D word rows, stacked nowhere:
-    a pass-through row is the verified hot row it equals (``objs_w``), any
-    other the next row of ``_fused_encode``'s result ``coded_w``."""
-    passthrough = _passthrough(code)
+    a pass-through row (``passthrough``) is the verified data row it
+    equals (``objs_w``), any other the next row of the coded ``coded_w``."""
     out = []
     for obj, coded in zip(objs_w, coded_w):
         rest = iter(coded)
         out.append([obj[passthrough[i]] if i in passthrough else next(rest)
-                    for i in range(code.n)])
+                    for i in range(len(passthrough) + len(coded))])
     return out
 
 
 def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
                    code, perm: np.ndarray, num_chunks: int, stagger: int,
-                   use_devices: bool, manifests: dict[int, dict],
-                   sched: dict | None, reclaim_hot: bool = True
-                   ) -> dict[int, dict]:
+                   on_chain: bool, manifests: dict[int, dict],
+                   sched: dict | None, reclaim_hot: bool = True,
+                   **extra) -> dict[int, dict]:
     """Encode one rectangular (same block length, same placement) batch of
-    hot steps and place/manifest the coded blocks."""
+    hot steps, place the coded blocks and ``_publish`` each step."""
     # blocks are loaded one group at a time (and released once the group
     # is written) so peak host memory is one group, not the batch
     rows = [_hot_rows(store, s, manifests[s], acfg.l) for s in grp]
-    B = manifests[grp[0]]["block_bytes"] // (acfg.l // 8)
-    nc = num_chunks
-    while nc > 1 and B % (gf.LANES[acfg.l] * nc):
-        nc //= 2
+    nc = streaming.chunk_count(
+        manifests[grp[0]]["block_bytes"] // (acfg.l // 8), acfg.l,
+        num_chunks)
     if sched is not None:
         sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
-    if use_devices and code.supports_chain_encode:
-        coded_w = np.asarray(multi_lib.pipelined_encode_many(
-            code, _gather(rows), num_chunks=nc, stagger=stagger,
-            order=_device_order(perm, sched is not None)))
+    if on_chain:
+        order = _device_order(perm, sched is not None)
+        if len(grp) == 1:
+            coded_w = np.asarray(chain_lib.pipelined_encode(
+                code, _gather(rows)[0], num_chunks=nc, order=order))[None]
+        else:
+            coded_w = np.asarray(multi_lib.pipelined_encode_many(
+                code, _gather(rows), num_chunks=nc, stagger=stagger,
+                order=order))
     else:
-        coded_w = _codewords(code, rows, _fused_encode(code, rows, acfg.l))
+        coded_w = _codewords(_passthrough(code), rows,
+                             _fused_encode(code, rows, acfg.l))
     out: dict[int, dict] = {}
     for b, step in enumerate(grp):
-        coded_blobs = _rows(coded_w[b])
-        for pos in range(acfg.n):
-            store.put(int(perm[pos]), ARC.format(step=step, i=pos),
-                      coded_blobs[pos])
-        manifest = manifests[step]
-        if reclaim_hot:
-            for node, held in enumerate(manifest["placement"]):
-                for j in held:
-                    store.delete(node, HOT.format(step=step, j=j))
-        manifest = {
-            **manifest, "tier": "archive", "family": acfg.family,
-            "perm": [int(p) for p in perm],
-            "coded_digests": [digest(b) for b in coded_blobs],
-            "orig_digests": manifest["digests"],
-            "batched_with": [int(s) for s in grp],
-        }
-        if not reclaim_hot:
-            manifest["hot_retained"] = True
-        if sched is not None:
-            manifest["sched"] = sched
-        _put_manifest(store, step, manifest)
-        out[step] = manifest
+        blobs = _put_coded(store, step, perm, coded_w[b])
+        out[step] = _publish(
+            store, step, manifests[step], acfg, perm,
+            (digest(blob) for blob in blobs), reclaim_hot=reclaim_hot,
+            sched=sched, **extra)
     return out
 
 
@@ -667,11 +637,11 @@ def archive_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
     """Batched migration: archive B hot steps CONCURRENTLY (paper §VI).
 
     All steps' objects are encoded together — on an n-device mesh via the
-    staggered multi-chain (one shard_map launch interleaving every object's
-    coding chain over the same nodes), off-device via ONE fused batched
-    pallas launch (the object axis rides the kernel grid). Steps whose block
-    lengths differ are grouped so each fused encode sees a rectangular
-    (B, k, block_len) batch. Returns the updated manifests in step order.
+    staggered multi-chain, off-device via ONE fused batched pallas launch
+    (the object axis rides the kernel grid). Steps whose block lengths
+    differ are grouped so each encode sees a rectangular (B, k, block_len)
+    batch. Returns the updated manifests in step order; each records its
+    group (``batched_with``).
 
     ``topology`` engages the multi-chain scheduler
     (``repro.core.scheduler.plan_many``): when the cluster holds at least
@@ -681,8 +651,7 @@ def archive_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
     records its placement (``perm`` / ``sched``) so repair reuses it.
     """
     code = acfg.code()
-    if use_devices is None:
-        use_devices = len(jax.devices()) >= acfg.n
+    on_chain = _use_chain(code, use_devices, acfg.n)
 
     manifests: dict[int, dict] = {}
     groups: dict[int, list[int]] = {}
@@ -695,47 +664,42 @@ def archive_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
 
     out: dict[int, dict] = {}
     for block_bytes, grp in groups.items():
-        if topology is not None:
+        # (steps, perm, num_chunks, sched) of each chain
+        if topology is None:
+            chains = [(grp, *_plan_placement(acfg, block_bytes, None,
+                                             node_speeds))]
+        else:
             from repro.core import scheduler
             mplan = scheduler.plan_many(topology, len(grp), acfg.n, acfg.k,
                                         float(block_bytes), stagger=stagger)
             by_chain: dict[int, list[int]] = {}
             for b, s in enumerate(grp):
                 by_chain.setdefault(mplan.assignment[b], []).append(s)
+            chains = []
             for g, sub in sorted(by_chain.items()):
-                plan = mplan.plans[g]
-                out.update(_archive_group(
-                    store, sub, acfg, code, np.asarray(plan.order),
-                    plan.num_chunks, stagger, use_devices, manifests,
-                    {**plan.to_manifest(), "topology": topology.to_dict(),
-                     "chain_group": int(g)}, reclaim_hot=reclaim_hot))
-        else:
-            if node_speeds is not None:
-                perm = chain_lib.order_chain(np.asarray(node_speeds),
-                                             acfg.n, acfg.k)
-            else:
-                perm = np.arange(acfg.n)
-            out.update(_archive_group(store, grp, acfg, code, perm,
-                                      acfg.num_chunks, stagger, use_devices,
-                                      manifests, None,
-                                      reclaim_hot=reclaim_hot))
+                p = mplan.plans[g]
+                chains.append((sub, np.asarray(p.order), p.num_chunks, {
+                    **p.to_manifest(), "topology": topology.to_dict(),
+                    "chain_group": int(g)}))
+        for sub, perm, nc, sched in chains:
+            out.update(_archive_group(
+                store, sub, acfg, code, perm, nc, stagger, on_chain,
+                manifests, sched, reclaim_hot,
+                batched_with=[int(s) for s in sub]))
     return [out[s] for s in steps]
 
 
 def reclaim_replicas(store: NodeStore, step: int) -> dict | None:
     """Drop a retained hot tier AFTER digest-verifying the archived copy.
 
-    ``archive_step``/``archive_many`` with ``reclaim_hot=False`` leave the
-    replicas on disk; this is the second phase of that two-phase migration.
-    The replicas are deleted only once ALL n coded blocks are present on
-    their manifest-recorded nodes and match their recorded digests — a
-    missing or corrupt shard (e.g. its write landed on a node that died
-    mid-archival) defers the reclaim (returns None) until the scrubber has
-    healed it; a digest-MISMATCHED shard is deleted on the spot (it is
-    provably not the data), demoting corruption to the missing-shard state
-    the repair path heals. Returns the updated manifest on success, the
-    manifest unchanged if the step holds no retained replicas (idempotent),
-    and raises ValueError for a step that was never archived.
+    The second phase of a ``reclaim_hot=False`` migration: the replicas
+    are deleted only once ALL n coded blocks are on their recorded nodes
+    and match their digests. A missing or corrupt shard defers the reclaim
+    (returns None) until the scrubber has healed it; a digest-MISMATCHED
+    shard is deleted on the spot, demoting it to the missing-shard state
+    the repair path heals. Returns the updated manifest, the manifest
+    unchanged if no replicas are retained (idempotent); raises ValueError
+    for a step that was never archived.
     """
     manifest = get_manifest(store, step)
     if manifest["tier"] == "hot":
@@ -750,9 +714,7 @@ def reclaim_replicas(store: NodeStore, step: int) -> dict | None:
             if pos not in alive and store.has(manifest["perm"][pos], rel):
                 store.delete(manifest["perm"][pos], rel)
         return None                      # unverified shards: keep the replicas
-    for node, held in enumerate(manifest["placement"]):
-        for j in held:
-            store.delete(node, HOT.format(step=step, j=j))
+    _drop_hot(store, step, manifest)
     manifest = {**manifest, "hot_retained": False}
     _put_manifest(store, step, manifest)
     return manifest
@@ -1095,8 +1057,6 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
         # per object, its helpers' verified buffers as word views (no copy)
         rows = [[np.frombuffer(raw, dtype=gf.WORD_DTYPE[l])
                  for raw in state[s][2]] for s in grp]
-        on_mesh = (len(jax.devices()) >= len(helpers)
-                   if use_devices is None else use_devices)
         if not code.positionwise:
             # sub-packetized repair (regenerating codes): per-object host
             # combine of the beta-sub-block helper summands
@@ -1104,22 +1064,18 @@ def repair_many(store: NodeStore, steps: list[int], acfg: ArchiveConfig,
             repaired_w = np.stack([
                 code.repair_np(missing, helpers, shards_w[b])
                 for b in range(len(grp))])
-        elif on_mesh:
+        elif _use_chain(code, use_devices, len(helpers), repair=True):
             shards_w = _gather(rows)            # (B_obj, |helpers|, B)
+            stream = manifests[grp[0]].get("streaming") or {}
+            sc_bytes = (stream.get("superchunk_bytes")  # archive's geometry
+                        if superchunk_bytes is None else superchunk_bytes)
+            sc_words = (None if sc_bytes is None
+                        else max(1, sc_bytes // (l // 8)))
             nc = acfg.num_chunks
-            sc_words = None
-            wb = l // 8
-            if superchunk_bytes is not None:
-                sc_words = max(1, superchunk_bytes // wb)
-            else:
-                stream = manifests[grp[0]].get("streaming")
-                if stream:              # heal with the archive's geometry
-                    sc_words = stream["superchunk_bytes"] // wb
             if sc_words is None or sc_words >= shards_w.shape[-1]:
                 # identity plan: the monolithic chunking rules apply
                 sc_words = None
-                while nc > 1 and shards_w.shape[-1] % (gf.LANES[l] * nc):
-                    nc //= 2
+                nc = streaming.chunk_count(shards_w.shape[-1], l, nc)
             repaired_w = np.asarray(repair_lib.pipelined_repair_many(
                 code, helpers, shards_w, missing, num_chunks=nc,
                 stagger=stagger, superchunk_words=sc_words))
@@ -1300,12 +1256,9 @@ def publish_device_archive(store: NodeStore, step: int, acfg: ArchiveConfig,
     the coded tier and publish its manifest.
 
     ``repro.checkpoint.devio`` computes ``blocks`` (k, B) and ``coded``
-    (n, B) in ONE on-device program; this is the storage-side half — shard
-    placement (codeword row i on node i), digests for both the original
-    blocks (what host restore verifies decode against) and the coded blobs
-    (what liveness probes verify), and a manifest every existing reader —
-    ``restore_blocks`` / ``repair`` / ``read_range`` — consumes unchanged.
-    No hot replicas ever hit disk on this path.
+    (n, B) in ONE on-device program; this is the storage-side half:
+    codeword row i on node i, and a manifest every reader consumes
+    unchanged. No hot replicas ever hit disk on this path.
     """
     if blocks.shape != (acfg.k, blocks.shape[1]) or blocks.dtype != np.uint8:
         raise ValueError(f"blocks must be (k={acfg.k}, B) uint8, "
@@ -1313,28 +1266,12 @@ def publish_device_archive(store: NodeStore, step: int, acfg: ArchiveConfig,
     if coded.shape != (acfg.n, blocks.shape[1]):
         raise ValueError(f"coded must be (n={acfg.n}, B={blocks.shape[1]}), "
                          f"got {coded.shape}")
-    orig_digests = [digest(blocks[j].tobytes()) for j in range(acfg.k)]
-    coded_blobs = [coded[i].tobytes() for i in range(acfg.n)]
-    for pos in range(acfg.n):
-        store.put(pos, ARC.format(step=step, i=pos), coded_blobs[pos])
-    manifest = {
-        "step": step, "tier": "archive", "n": acfg.n, "k": acfg.k,
-        "l": acfg.l, "seed": acfg.seed, "family": acfg.family,
-        "block_bytes": int(blocks.shape[1]),
-        "digests": orig_digests,
-        # nominal hot placement (no replicas ever existed): keeps the
-        # manifest schema one shape across write paths
-        "placement": [list(h) for h in rapidraid.placement(acfg.n, acfg.k)],
-        "perm": list(range(acfg.n)),
-        "coded_digests": [digest(b) for b in coded_blobs],
-        "orig_digests": orig_digests,
-        "blob_len": int(blob_len),
-        "device_direct": True,
-    }
-    if state_key is not None:
-        manifest["state_key"] = state_key
-    _put_manifest(store, step, manifest)
-    return manifest
+    base = _base_manifest(step, acfg, blocks.shape[1],
+                          [digest(b) for b in _rows(blocks)], tier="archive")
+    blobs = _put_coded(store, step, range(acfg.n), coded)
+    return _publish(store, step, base, acfg, range(acfg.n),
+                    (digest(b) for b in blobs), blob_len=int(blob_len),
+                    device_direct=True, state_key=state_key)
 
 
 def publish_streaming_archive(store: NodeStore, step: int,
@@ -1348,86 +1285,26 @@ def publish_streaming_archive(store: NodeStore, step: int,
     The checkpoint streaming route (``repro.checkpoint.devio.save_state``
     above its ``footprint_bytes`` threshold): the train state's blocks are
     already on the host, but the ENCODE must not materialize the object on
-    the devices — each super-chunk stripe runs through one cached chain
-    program and frames straight into atomic ``put_stream`` writers. Same
-    manifest contract as ``publish_device_archive`` plus the ``streaming``
-    stripe records; no hot replicas ever hit disk.
+    the devices: its stripes are slices of the blocks, coded through
+    ``archive_step``'s stripe body. Same manifest contract as
+    ``publish_device_archive`` plus the ``streaming`` stripe records.
     """
     code = acfg.code()
-    if not code.positionwise:
-        raise ValueError(
-            f"publish_streaming_archive: {code.family} is sub-packetized — "
-            f"stripe concatenation is not a codeword")
     if blocks.ndim != 2 or blocks.shape[0] != acfg.k \
             or blocks.dtype != np.uint8:
         raise ValueError(f"blocks must be (k={acfg.k}, B) uint8, "
                          f"got {blocks.shape} {blocks.dtype}")
-    n, l = acfg.n, acfg.l
-    wb = l // 8
-    B = blocks.shape[1]
-    nc = acfg.num_chunks
-    plan = streaming.plan_stream(B // wb, max(1, superchunk_bytes // wb),
-                                 l=l, num_chunks=nc)
-    if not plan.streaming:
-        while nc > 1 and (B // wb) % (gf.LANES[l] * nc):
-            nc //= 2
-    data_w = _words(blocks, l)
-    writers = [store.put_stream(pos, ARC.format(step=step, i=pos))
-               for pos in range(n)]
-    stripes: list[dict] = []
-
-    def sink(s: int, out_w: np.ndarray) -> None:
-        frame = _u8(np.asarray(out_w))
-        recs = []
-        for pos in range(n):
-            blob = frame[pos].tobytes()
-            writers[pos].write(blob)
-            recs.append(digest(blob))
-        stripes.append({"words": int(out_w.shape[-1]),
-                        "coded_digests": recs})
-
-    if use_devices is None:
-        use_devices = len(jax.devices()) >= n
-    try:
-        if use_devices and code.supports_chain_encode:
-            fn = chain_lib.encode_program(code, plan.sc_words, nc)
-            streaming.run_words(fn, data_w, plan, sink=sink)
-        else:
-            for s in range(plan.num_superchunks):
-                lo, hi = plan.stripe_span(s)
-                stripe = data_w[:, lo:hi]
-                if hi - lo < plan.sc_words:   # zero-pad the tail stripe
-                    stripe = np.concatenate(
-                        [stripe, np.zeros((acfg.k, plan.sc_words - (hi - lo)),
-                                          data_w.dtype)], axis=1)
-                sink(s, np.asarray(code.encode_np(stripe))[:, :hi - lo])
-    except BaseException:
-        for w in writers:
-            w.abort()
-        raise
-    for w in writers:
-        w.close()
-
-    manifest = {
-        "step": step, "tier": "archive", "n": n, "k": acfg.k, "l": l,
-        "seed": acfg.seed, "family": acfg.family, "block_bytes": int(B),
-        "digests": [digest(blocks[j].tobytes()) for j in range(acfg.k)],
-        "placement": [list(h) for h in rapidraid.placement(n, acfg.k)],
-        "perm": list(range(n)),
-        "coded_digests": [w.digest() for w in writers],
-        "blob_len": int(blob_len),
-        "streaming": {
-            "num_superchunks": int(plan.num_superchunks),
-            "superchunk_bytes": int(plan.sc_words * wb),
-            "num_chunks": int(nc),
-            "stripes": stripes,
-        },
-    }
-    manifest["orig_digests"] = manifest["digests"]
-    if state_key is not None:
-        manifest["state_key"] = state_key
-    _put_manifest(store, step, manifest)
-    return manifest
+    wb = acfg.l // 8
+    blocks = np.ascontiguousarray(blocks)
+    plan = streaming.plan_stream(blocks.shape[1] // wb,
+                                 max(1, superchunk_bytes // wb), l=acfg.l,
+                                 num_chunks=acfg.num_chunks)
+    base = _base_manifest(step, acfg, blocks.shape[1], None, tier="archive")
+    return _archive_step_streaming(
+        store, step, acfg, base, code, range(acfg.n), acfg.num_chunks, None,
+        plan, _use_chain(code, use_devices, acfg.n), False,
+        lambda j, offset, nbytes: blocks[j, offset:offset + nbytes],
+        blob_len=int(blob_len), state_key=state_key)
 
 
 # ---------------------------------------------------------------------------
