@@ -30,13 +30,18 @@ Every span the program emits: name, site, args.
   on the host).
 * ``kernel_launch``: the coding kernel's dispatch with its pack and unpack,
   per launch or per stripe; host side, it returns before the device ends;
-  ``kernel``.
-* ``d2h``: ``np.asarray`` of the kernel's result, which waits for the
-  device and then copies, per launch or per stripe (whose copy back was
-  started when it was dispatched); ``bytes``, only the rows copied back.
-  A fused archive codes only the generator rows that are not unit rows,
-  so a systematic code's data rows never cross: ``_fused_encode``'s
-  ``passthrough`` counts the rows written from the verified hot buffers
+  ``kernel``. The fused archive's (``_fused_encode``) also splits the
+  result into one device array per coded row and starts each row's copy
+  back; its ``streamed`` counts those rows (O * R).
+* ``d2h``: ``np.asarray`` of a kernel result, which waits for the device
+  and the copy back; ``bytes``, only the rows copied back. One per repair
+  launch and one per stripe (whose copy back was started when it was
+  dispatched). In the fused archive one per coded row, taken as
+  ``archive._put_coded`` writes it, so the writes and hashes of the rows
+  before it overlap its copy; ``row``, its codeword row. A fused archive
+  codes only the generator rows that are not unit rows, so a systematic
+  code's data rows never cross: the launch's first ``d2h`` carries
+  ``passthrough``, the rows written from the verified hot buffers
   instead (k per object for LRC and Xorbas, 0 for RapidRAID and MBR).
 * ``stripe_read``: one per stripe in the one stripe body,
   ``archive._archive_step_streaming``, which codes a streamed

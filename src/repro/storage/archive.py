@@ -274,21 +274,33 @@ def _drop_hot(store: NodeStore, step: int, manifest: dict) -> None:
             store.delete(node, HOT.format(step=step, j=j))
 
 
-def _put_coded(store: NodeStore, step: int, perm, rows) -> list[memoryview]:
-    """Write codeword row p on node ``perm[p]``; -> the rows as written."""
-    blobs = _rows(rows)
-    for pos, blob in enumerate(blobs):
+def _put_coded(store: NodeStore, step: int, perm, rows,
+               **d2h) -> list[str]:
+    """Write codeword row p on node ``perm[p]`` and hash it as written;
+    -> the rows' digests. A row is a host array, or a device row whose copy
+    back began at dispatch (``_fused_encode``): that one is taken only now,
+    in its own ``d2h`` span (arg ``row``: p), so the writes and hashes of
+    the rows before it overlap its copy; ``d2h`` joins the first such
+    span's args."""
+    digests = []
+    for pos, row in enumerate(rows):
+        if isinstance(row, jax.Array):
+            with span("d2h", bytes=row.nbytes, row=pos, **d2h):
+                row = np.asarray(row)
+            d2h = {}
+        blob = memoryview(_u8(row))
         store.put(int(perm[pos]), ARC.format(step=step, i=pos), blob)
-    return blobs
+        digests.append(digest(blob))
+    return digests
 
 
 def _publish(store: NodeStore, step: int, base: dict, acfg: ArchiveConfig,
-             perm, coded_digests, *, reclaim_hot: bool = True,
+             perm, coded_digests: list[str], *, reclaim_hot: bool = True,
              sched: dict | None = None, **extra) -> dict:
-    """Publish a step whose n coded rows are stored. A step coded from the
-    hot tier first drops its replicas (2x -> n/k) or, with ``reclaim_hot``
-    false, records ``hot_retained``; ``coded_digests`` is read after that.
-    ``extra`` entries that are not None join the manifest."""
+    """Publish a step whose n coded rows are stored and hashed. A step
+    coded from the hot tier first drops its replicas (2x -> n/k) or, with
+    ``reclaim_hot`` false, records ``hot_retained``. ``extra`` entries
+    that are not None join the manifest."""
     hot = base["tier"] == "hot"
     if hot and reclaim_hot:
         with span("reclaim"):
@@ -551,14 +563,41 @@ def _passthrough(code) -> types.MappingProxyType:
     return types.MappingProxyType(out)
 
 
-def _fused_encode(code, objs_w, l: int) -> np.ndarray:
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _split_rows(coded: jax.Array, objects: int,
+                rows: int) -> tuple[jax.Array, ...]:
+    """A launch's coded words -> its O * R rows as 1-D device arrays, in
+    one dispatch, so each row's copy back can start on its own."""
+    x = coded.reshape(objects, rows, -1)
+    return tuple(x[o, r] for o in range(objects) for r in range(rows))
+
+
+class _DeviceRows(tuple):
+    """The (O, R, W) coded words of one launch as O tuples of R 1-D device
+    rows, each row's copy back started at dispatch. ``_put_coded`` takes
+    each row as it writes it; ``copy()`` (or ``np.asarray``) waits for all
+    and gives the (O, R, W) host array."""
+
+    def __array__(self, dtype=None, copy=None):
+        rows = [row for obj in self for row in obj]
+        with span("d2h", bytes=sum(row.nbytes for row in rows)):
+            out = np.stack([np.asarray(row) for row in rows])
+        return out.reshape(len(self), -1, out.shape[-1]).astype(
+            dtype or out.dtype, copy=False)
+
+    def copy(self) -> np.ndarray:
+        return np.asarray(self)
+
+
+def _fused_encode(code, objs_w, l: int) -> _DeviceRows:
     """O objects (sequences of k 1-D word rows) -> (O, R, B) words of the
     R codeword rows that are not pass-through rows (``_passthrough``), in
     row order, in ONE fused kernel launch, the object axis on the kernel
     grid; ``_codewords`` puts the n rows together. Positionwise rows go to
     the device as they are (``_to_device``); a regenerating code's
     sub-packetized message is built on the host, so EVERY family encodes
-    through the same fused GF kernel.
+    through the same fused GF kernel. Returns without waiting: every row's
+    copy back is started here, and ``_put_coded`` takes each as it lands.
     """
     from repro.kernels.gf_encode import ops as kernel_ops
     O = len(objs_w)
@@ -572,18 +611,21 @@ def _fused_encode(code, objs_w, l: int) -> np.ndarray:
             msgs_dev = jnp.asarray(msgs)
     passthrough = _passthrough(code)
     coded = [i for i in range(len(code.G)) if i not in passthrough]
-    with span("kernel_launch", kernel="encode_auto"):
-        rows_dev = kernel_ops.encode_auto(code.G[coded], msgs_dev, l)
-    with span("d2h", bytes=rows_dev.nbytes,
-              passthrough=O * len(passthrough)):
-        rows = np.asarray(rows_dev)
-    return rows.reshape(O, code.n - len(passthrough), -1)
+    R = code.n - len(passthrough)
+    with span("kernel_launch", kernel="encode_auto", streamed=O * R):
+        rows = _split_rows(kernel_ops.encode_auto(code.G[coded], msgs_dev, l),
+                           O, R)
+        for row in rows:
+            row.copy_to_host_async()
+    return _DeviceRows(rows[o * R:(o + 1) * R] for o in range(O))
 
 
-def _codewords(passthrough, objs_w, coded_w) -> list[list[np.ndarray]]:
+def _codewords(passthrough, objs_w, coded_w) -> list[list]:
     """Per object, its n codeword rows as 1-D word rows, stacked nowhere:
     a pass-through row (``passthrough``) is the verified data row it
-    equals (``objs_w``), any other the next row of the coded ``coded_w``."""
+    equals (``objs_w``), any other the next row of the coded ``coded_w``
+    (an (O, R, W) host array, or ``_fused_encode``'s device rows, left on
+    the device until ``_put_coded`` takes them)."""
     out = []
     for obj, coded in zip(objs_w, coded_w):
         rest = iter(coded)
@@ -607,6 +649,7 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
         num_chunks)
     if sched is not None:
         sched = {**sched, "num_chunks": int(nc)}  # record what actually ran
+    d2h = {}        # args of the first row's copy back on the fused path
     if on_chain:
         order = _device_order(perm, sched is not None)
         if len(grp) == 1:
@@ -617,15 +660,17 @@ def _archive_group(store: NodeStore, grp: list[int], acfg: ArchiveConfig,
                 code, _gather(rows), num_chunks=nc, stagger=stagger,
                 order=order))
     else:
-        coded_w = _codewords(_passthrough(code), rows,
+        passthrough = _passthrough(code)
+        d2h["passthrough"] = len(grp) * len(passthrough)
+        coded_w = _codewords(passthrough, rows,
                              _fused_encode(code, rows, acfg.l))
     out: dict[int, dict] = {}
     for b, step in enumerate(grp):
-        blobs = _put_coded(store, step, perm, coded_w[b])
-        out[step] = _publish(
-            store, step, manifests[step], acfg, perm,
-            (digest(blob) for blob in blobs), reclaim_hot=reclaim_hot,
-            sched=sched, **extra)
+        digests = _put_coded(store, step, perm, coded_w[b], **d2h)
+        d2h = {}
+        out[step] = _publish(store, step, manifests[step], acfg, perm,
+                             digests, reclaim_hot=reclaim_hot, sched=sched,
+                             **extra)
     return out
 
 
@@ -1268,10 +1313,10 @@ def publish_device_archive(store: NodeStore, step: int, acfg: ArchiveConfig,
                          f"got {coded.shape}")
     base = _base_manifest(step, acfg, blocks.shape[1],
                           [digest(b) for b in _rows(blocks)], tier="archive")
-    blobs = _put_coded(store, step, range(acfg.n), coded)
     return _publish(store, step, base, acfg, range(acfg.n),
-                    (digest(b) for b in blobs), blob_len=int(blob_len),
-                    device_direct=True, state_key=state_key)
+                    _put_coded(store, step, range(acfg.n), coded),
+                    blob_len=int(blob_len), device_direct=True,
+                    state_key=state_key)
 
 
 def publish_streaming_archive(store: NodeStore, step: int,

@@ -116,7 +116,8 @@ def test_copy_back_counts_only_the_coded_rows(tmp_path, family, path,
                                               monkeypatch):
     """``passthrough`` counts the rows written from hot buffers (k per
     object or stripe, 0 for RapidRAID) and ``d2h``'s ``bytes`` only the
-    rows copied back."""
+    rows copied back: per stripe when striped, else one ``d2h`` per coded
+    row, ``passthrough`` on the launch's first."""
     code, manifests, seen, _ = _archive(tmp_path, family, path, monkeypatch)
     through = 0 if family == "rapidraid" else code.k
     d2h = [a for n, a in seen if n == "d2h"]
@@ -129,7 +130,9 @@ def test_copy_back_counts_only_the_coded_rows(tmp_path, family, path,
         assert [a["bytes"] for a in d2h] == [(N - through) * STRIPE * 2] * 5
     else:
         objects = len(manifests)
-        (args,) = d2h
-        assert args["passthrough"] == objects * through
-        assert args["bytes"] == objects * (N - through) * WORDS * 2
+        assert len(d2h) == objects * (N - through)
+        assert [a.get("passthrough") for a in d2h] == \
+            [objects * through] + [None] * (len(d2h) - 1)
+        assert [a["row"] for a in d2h] == list(range(through, N)) * objects
+        assert [a["bytes"] for a in d2h] == [WORDS * 2] * len(d2h)
     assert "host_copy" not in {n for n, _ in seen}

@@ -141,8 +141,8 @@ def test_byte_counts_on_the_spans(traced):
     assert hot["bytes"] == ACFG.k * BLOCK
     (h2d,) = [s for n, *_, s in archive if n == "h2d"]
     assert h2d["bytes"] == ACFG.k * BLOCK
-    (d2h,) = [s for n, *_, s in archive if n == "d2h"]
-    assert d2h["bytes"] == ACFG.n * BLOCK
+    d2h = [s for n, *_, s in archive if n == "d2h"]
+    assert [s["bytes"] for s in d2h] == [BLOCK] * ACFG.n   # one per row
     decode = [s for n, *_, s in _inside(events, "read_range")
               if n == "read_decode"]
     assert len(decode) == 2                    # one per touched block
@@ -201,10 +201,16 @@ def test_stored_bytes_unchanged_by_the_profiler(traced):
 def test_d2h_copies_back_only_rows_that_are_not_data(request, session, acfg,
                                                      through):
     """A systematic code's data rows are written from the hot buffers
-    (``passthrough``), so ``d2h`` copies back only the other rows; the
-    profiler leaves the stored bytes as they are."""
+    (``passthrough``, on the first row's span), so ``d2h`` copies back only
+    the other rows, one span each, every copy started at the launch
+    (``kernel_launch``'s ``streamed``); the profiler leaves the stored
+    bytes as they are."""
     events, plain, traced_files = request.getfixturevalue(session)
-    (d2h,) = [s for n, *_, s in _inside(events, "archive") if n == "d2h"]
-    assert d2h["passthrough"] == through
-    assert d2h["bytes"] == (acfg.n - through) * BLOCK
+    archive = _inside(events, "archive")
+    d2h = [s for n, *_, s in archive if n == "d2h"]
+    assert [s["row"] for s in d2h] == list(range(through, acfg.n))
+    assert d2h[0]["passthrough"] == through
+    assert sum(s["bytes"] for s in d2h) == (acfg.n - through) * BLOCK
+    (launch,) = [s for n, *_, s in archive if n == "kernel_launch"]
+    assert launch["streamed"] == acfg.n - through
     assert plain == traced_files

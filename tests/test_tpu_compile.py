@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import codes, fault_tolerance, gf
 from repro.kernels.gf_encode import kernel, ops
-from repro.storage import chain, multi, repair
+from repro.storage import archive, chain, multi, repair
 
 BLOCK_BYTES = 64 << 20
 WORDS = BLOCK_BYTES // 2            # GF(2^16) words per block
@@ -131,6 +131,20 @@ def test_fused_encode_program(one_chip, entry):
                              jnp.uint32, sharding=one_chip)
     block = ops.pick_block(LANES_U32)
     _compile(ops._encode_packed_jit, x, _key(M), 16, block, False)
+
+
+@pytest.mark.parametrize("objects, rows", [(1, 16), (2, 16), (1, 4)],
+                         ids=["archive", "archive_many", "archive_parity"])
+def test_row_split_program(one_chip, objects, rows):
+    """The fused archive's split of its coded result into one device array
+    per row (each copied back on its own) needs no temporary buffer: its
+    outputs are the rows' bytes and nothing more."""
+    x = jax.ShapeDtypeStruct((objects, rows, WORDS), jnp.uint16,
+                             sharding=one_chip)
+    mem = archive._split_rows.lower(x, objects, rows).compile() \
+        .memory_analysis()
+    assert mem.temp_size_in_bytes < BLOCK_BYTES
+    assert mem.output_size_in_bytes < (objects * rows + 1) * BLOCK_BYTES
 
 
 def test_pack_unpack_program(one_chip):
